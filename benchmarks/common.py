@@ -56,6 +56,8 @@ def dense_figure_cli(run_fn: Callable, artifact: str, argv=None) -> None:
     the suites' gate stack pins it cell-for-cell against a fresh
     measure-mode sweep."""
     import argparse
+
+    from repro.launch.compile_cache import enable_compile_cache
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="CI size axis (gates run at every size)")
@@ -66,6 +68,7 @@ def dense_figure_cli(run_fn: Callable, artifact: str, argv=None) -> None:
                     choices=["measure", "batched"],
                     help="cell evaluation mode (default: measure)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     emit(run_fn(smoke=args.smoke or None, workers=args.workers,
                 mode=args.mode),
          save_as=artifact)

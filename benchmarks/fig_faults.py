@@ -330,6 +330,8 @@ def main(argv=None) -> None:
     harness gate is opt-in: it re-runs the nested campaign twice)."""
     import argparse
 
+    from repro.launch.compile_cache import enable_compile_cache
+
     from .common import emit
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
@@ -345,6 +347,7 @@ def main(argv=None) -> None:
                     help="also run the chaos gate: sharded sweep with an "
                          "injected worker kill + hang must equal serial")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     emit(run(smoke=args.smoke or None, workers=args.workers,
              mode=args.mode, chaos=args.chaos),
          save_as=ARTIFACT)

@@ -39,6 +39,8 @@ import os
 import sys
 import time
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import (fig3_cg_recompute, fig4_cg_runtime, fig7_mm_recompute,
                fig8_mm_runtime, fig10_12_mc_correctness, fig13_mc_runtime,
                fig_faults, fig_kv, fig_torn, kernel_bench, scenarios_sweep,
@@ -82,6 +84,7 @@ def main() -> None:
                     help="processes for scenario sweeps "
                          "(exports REPRO_SWEEP_WORKERS)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.backend:
         os.environ["REPRO_NVM_BACKEND"] = args.backend
     if args.smoke:

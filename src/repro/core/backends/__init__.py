@@ -11,7 +11,7 @@ implementations ship here:
 * ``device`` — :class:`DeviceBackend`, the vectorized backend with
   large eviction-free span ops and queue-validity scans lifted onto
   jax-jit kernels; byte-equivalent to both, falls back to the
-  vectorized host path without jax or under eviction pressure.
+  vectorized host path below a size floor or under eviction pressure.
 
 Select with ``NVMConfig(backend="...")`` or the ``REPRO_NVM_BACKEND``
 environment variable. See README.md in this directory.

@@ -29,16 +29,15 @@ fork ladder, snapshot tiering, and fault injection run on top of it
 with the established cross-backend byte-identity contracts intact
 (gated by tests/test_backend_equivalence.py).
 
-The kernels are plain jnp under ``enable_x64``: per the accelerator
+The kernels are plain jnp under ``jax.enable_x64``: per the accelerator
 guide, these transitions are memory-bound elementwise/gather ops that
 XLA already fuses into single kernels — a hand-written Pallas grid
 would add block-spec bookkeeping for no arithmetic win (unlike the
 ABFT/CG launches in ``repro.kernels``, which are MXU-shaped). Shapes
 are padded to powers of two so jit compiles log-many variants.
 
-Without jax (or below :data:`DeviceBackend.MIN_DEVICE_ENTRIES`, where
-dispatch overhead dominates) every path falls back to the parent, so
-``REPRO_NVM_BACKEND=device`` is always safe to select.
+Below :data:`DeviceBackend.MIN_DEVICE_ENTRIES`, where dispatch overhead
+dominates, every path falls back to the parent.
 
 Worker-pool caveat: the first device op instantiates an XLA backend in
 this process; forking after that deadlocks children's device math. The
@@ -74,7 +73,7 @@ class DeviceBackend(VectorizedBackend):
         e_lo = lo // r.epe
         e_hi = (hi - 1) // r.epe + 1
         m = e_hi - e_lo
-        if m < self.MIN_DEVICE_ENTRIES or not _dev.have_jax():
+        if m < self.MIN_DEVICE_ENTRIES:
             super()._op(name, lo, hi, is_write)
             return
         sl = slice(e_lo, e_hi)
@@ -108,7 +107,7 @@ class DeviceBackend(VectorizedBackend):
     def _validity(self, rids: np.ndarray, ents: np.ndarray,
                   stamps: np.ndarray):
         n = rids.shape[0]
-        if n < self.MIN_DEVICE_ENTRIES or not _dev.have_jax():
+        if n < self.MIN_DEVICE_ENTRIES:
             return super()._validity(rids, ents, stamps)
         rid0 = int(rids[0])
         if not np.all(rids == rid0):

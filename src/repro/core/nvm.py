@@ -98,8 +98,8 @@ class NVMConfig:
     # leaves XSBench's counters stale-by-different-amounts in NVM (Fig. 10).
     replacement: str = "lru"
     # emulation backend: "vectorized" (default), "reference" (oracle), or
-    # "device" (jax-jit forward pass; falls back to vectorized semantics
-    # without jax) — all byte/stat-identical; overridable via the
+    # "device" (jax-jit forward pass on large spans) — all
+    # byte/stat-identical; overridable via the
     # REPRO_NVM_BACKEND environment variable.
     backend: str = dataclasses.field(default_factory=_default_backend)
 

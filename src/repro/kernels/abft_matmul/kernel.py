@@ -13,13 +13,16 @@ float32 VMEM scratch accumulates partial products across the k blocks
 writes, per (i, j) tile:
 
   * the C tile itself (cast to the output dtype),
-  * a (bm, 1) row partial sum    -> row_partials[:, j]
-  * a (1, bn) column partial sum -> col_partials[i, :]
+  * a (bm, 1) row partial sum    -> row_partials[j, i*bm:(i+1)*bm, 0]
+  * a (1, bn) column partial sum -> col_partials[i, 0, j*bn:(j+1)*bn]
 
-The tiny cross-tile reductions (summing partials over j / i) happen in
-ops.py as jnp ops — XLA fuses them, and keeping the kernel free of
-cross-tile accumulation avoids revisit-ordering hazards in the Mosaic
-pipeline.
+The partials carry a leading per-tile axis that the block specs squeeze
+away, so every block's last two dimensions are either multiples of
+(8, 128) or the whole array dimension (the 1-wide axis) — the Mosaic
+tiling rule. The tiny cross-tile reductions (summing partials over the
+tile axis) happen in ops.py as jnp ops — XLA fuses them, and keeping
+the kernel free of cross-tile accumulation avoids revisit-ordering
+hazards in the Mosaic pipeline.
 
 VMEM budget at the default 128-tile: a(64KB f32) + b(64KB) + acc(64KB) +
 c(64KB) + partials(~1KB) ≈ 256KB double-buffered ≈ 512KB — comfortably
@@ -51,10 +54,14 @@ def _abft_mm_kernel(a_ref, b_ref, c_ref, rowp_ref, colp_ref, acc_ref):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # accumulate in the scratch dtype (acc_dtype below): f32 feeds the
-    # MXU fast path, f64 the batched sweep's bit-stable CG invariants
+    # f32 operands need HIGHEST to keep f32 products on the MXU (the
+    # default rounds them to bf16: 1.4e-2 of max |C_f| off a float32
+    # reference at 4096^2 on v5e); Mosaic refuses it for bf16 operands,
+    # whose products are exact anyway
+    f32 = a_ref.dtype == jnp.float32
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype
+        a_ref[...], b_ref[...], preferred_element_type=acc_ref.dtype,
+        precision=jax.lax.Precision.HIGHEST if f32 else None,
     )
 
     @pl.when(kk == pl.num_programs(2) - 1)
@@ -68,7 +75,7 @@ def _abft_mm_kernel(a_ref, b_ref, c_ref, rowp_ref, colp_ref, acc_ref):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("bm", "bn", "bk", "out_dtype", "acc_dtype", "interpret"),
+    static_argnames=("bm", "bn", "bk", "out_dtype", "interpret"),
 )
 def abft_matmul_pallas(
     a: jax.Array,
@@ -78,15 +85,13 @@ def abft_matmul_pallas(
     bn: int = DEFAULT_BN,
     bk: int = DEFAULT_BK,
     out_dtype=None,
-    acc_dtype=jnp.float32,
     interpret: bool = False,
 ):
     """C = a @ b with fused row/col checksum partials.
 
     a: (m, k), b: (k, n); m % bm == k % bk == n % bn == 0 (ops.py pads).
-    Returns (C (m,n) out_dtype, row_partials (m, n/bn) acc_dtype,
-             col_partials (m/bm, n) acc_dtype); the VMEM accumulator is
-    ``acc_dtype`` too (default f32 — the historical behavior).
+    Returns (C (m,n) out_dtype, row_partials (n/bn, m, 1) f32,
+             col_partials (m/bm, 1, n) f32); the VMEM accumulator is f32.
     """
     m, k = a.shape
     k2, n = b.shape
@@ -105,14 +110,14 @@ def abft_matmul_pallas(
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((bm, 1), lambda i, j, kk: (i, j)),
-            pl.BlockSpec((1, bn), lambda i, j, kk: (i, j)),
+            pl.BlockSpec((None, bm, 1), lambda i, j, kk: (j, i, 0)),
+            pl.BlockSpec((None, 1, bn), lambda i, j, kk: (i, 0, j)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, n), out_dtype),
-            jax.ShapeDtypeStruct((m, nj), acc_dtype),
-            jax.ShapeDtypeStruct((mi, n), acc_dtype),
+            jax.ShapeDtypeStruct((nj, m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mi, 1, n), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a, b)

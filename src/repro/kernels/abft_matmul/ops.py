@@ -14,7 +14,7 @@ import jax.numpy as jnp
 
 from .kernel import DEFAULT_BK, DEFAULT_BM, DEFAULT_BN, abft_matmul_pallas
 
-__all__ = ["abft_matmul", "abft_matmul_full", "gemm_batch", "on_tpu"]
+__all__ = ["abft_matmul", "abft_matmul_full", "on_tpu"]
 
 
 def on_tpu() -> bool:
@@ -48,8 +48,8 @@ def _abft_matmul_impl(a, b, *, interpret: bool):
     c_p, rowp, colp = abft_matmul_pallas(
         a_p, b_p, bm=bm, bn=bn, bk=bk, interpret=interpret)
     c = c_p[:m, :n]
-    row_cs = jnp.sum(rowp, axis=1)[:m]   # (m,)  sum of partials over j
-    col_cs = jnp.sum(colp, axis=0)[:n]   # (n,)  sum of partials over i
+    row_cs = jnp.sum(rowp[:, :m, 0], axis=0)   # (m,)  sum over j tiles
+    col_cs = jnp.sum(colp[:, 0, :n], axis=0)   # (n,)  sum over i tiles
     return c, row_cs, col_cs
 
 
@@ -58,45 +58,6 @@ def abft_matmul(a: jax.Array, b: jax.Array, *, interpret: bool | None = None):
     if interpret is None:
         interpret = not on_tpu()
     return _abft_matmul_impl(a, b, interpret=interpret)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("acc_dtype", "use_pallas", "interpret"))
-def _gemm_batch_impl(a, b, *, acc_dtype, use_pallas, interpret):
-    if not use_pallas:
-        return jnp.dot(a.astype(acc_dtype), b.astype(acc_dtype),
-                       preferred_element_type=acc_dtype)
-    m, k = a.shape
-    _, n = b.shape
-    bm = _pick_block(m, DEFAULT_BM)
-    bn = _pick_block(n, DEFAULT_BN)
-    bk = _pick_block(k, DEFAULT_BK)
-    mp, kp, np_ = _round_up(m, bm), _round_up(k, bk), _round_up(n, bn)
-    a_p = jnp.pad(a.astype(acc_dtype), ((0, mp - m), (0, kp - k)))
-    b_p = jnp.pad(b.astype(acc_dtype), ((0, kp - k), (0, np_ - n)))
-    c_p, _rowp, _colp = abft_matmul_pallas(
-        a_p, b_p, bm=bm, bn=bn, bk=bk, out_dtype=jnp.dtype(acc_dtype),
-        acc_dtype=jnp.dtype(acc_dtype), interpret=interpret)
-    return c_p[:m, :n]
-
-
-def gemm_batch(a: jax.Array, b: jax.Array, *, acc_dtype=jnp.float64,
-               use_pallas: bool | None = None, interpret: bool = False):
-    """Row-stack GEMM ``a (B, k) @ b (k, n)`` accumulated in ``acc_dtype``.
-
-    The batched sweep engine's CG invariant scan stacks every candidate
-    overlay row of a whole sweep matrix into ``a`` and evaluates the
-    residual matvecs as one launch. ``use_pallas=None`` routes through
-    the fused-epilogue Pallas matmul on TPU (checksum partials computed
-    and discarded — the epilogue is fused, not an extra pass) and
-    ``jnp.dot`` elsewhere; equivalence of the two routes is pinned by
-    tests at small shapes with ``use_pallas=True, interpret=True``.
-    """
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    return _gemm_batch_impl(
-        a, b, acc_dtype=jnp.dtype(acc_dtype), use_pallas=bool(use_pallas),
-        interpret=bool(interpret))
 
 
 def abft_matmul_full(a: jax.Array, b: jax.Array, *,

@@ -10,7 +10,7 @@ import jax.numpy as jnp
 from ..abft_matmul.ops import on_tpu
 from .kernel import tile_sums_pallas
 
-__all__ = ["verify_checksums", "tile_sums", "tile_sums_batch"]
+__all__ = ["verify_checksums", "tile_sums"]
 
 
 def _round_up(x: int, mult: int) -> int:
@@ -32,47 +32,7 @@ def tile_sums(x: jax.Array, *, interpret: bool):
     mp, np_ = _round_up(m, bm), _round_up(n, bn)
     x_p = jnp.pad(x, ((0, mp - m), (0, np_ - n)))
     rowp, colp = tile_sums_pallas(x_p, bm=bm, bn=bn, interpret=interpret)
-    return jnp.sum(rowp, axis=1)[:m], jnp.sum(colp, axis=0)[:n]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("acc_dtype", "use_pallas", "interpret"))
-def _tile_sums_batch_impl(x, *, acc_dtype, use_pallas, interpret):
-    B, m, n = x.shape
-    if not use_pallas:
-        xa = x.astype(acc_dtype)
-        return jnp.sum(xa, axis=2), jnp.sum(xa, axis=1)
-    bm, bn = _pick_block(m), _pick_block(n)
-    mp, np_ = _round_up(m, bm), _round_up(n, bn)
-    x_p = jnp.pad(x, ((0, 0), (0, mp - m), (0, np_ - n)))
-
-    def one(xi):
-        rowp, colp = tile_sums_pallas(
-            xi, bm=bm, bn=bn, acc_dtype=acc_dtype, interpret=interpret)
-        return jnp.sum(rowp, axis=1)[:m], jnp.sum(colp, axis=0)[:n]
-
-    return jax.vmap(one)(x_p)
-
-
-def tile_sums_batch(x: jax.Array, *, acc_dtype=jnp.float32,
-                    use_pallas: bool | None = None,
-                    interpret: bool = False):
-    """Batched row/col sums of a stack of matrices x (B, m, n).
-
-    Returns (row_sums (B, m), col_sums (B, n)) accumulated in
-    ``acc_dtype``. The batched sweep engine's ABFT chunk screen calls
-    this once over every examined chunk image of a whole sweep matrix.
-
-    ``use_pallas=None`` routes through the Pallas kernel on TPU and
-    plain XLA reductions elsewhere (Pallas interpret mode is far too
-    slow for the CPU hot path; equivalence of the two routes is pinned
-    by tests at small shapes with ``use_pallas=True, interpret=True``).
-    """
-    if use_pallas is None:
-        use_pallas = on_tpu()
-    return _tile_sums_batch_impl(
-        x, acc_dtype=jnp.dtype(acc_dtype), use_pallas=bool(use_pallas),
-        interpret=bool(interpret))
+    return jnp.sum(rowp[:, :m, 0], axis=0), jnp.sum(colp[:, 0, :n], axis=0)
 
 
 def verify_checksums(cf: jax.Array, rtol: float = 1e-6, atol: float = 1e-4,

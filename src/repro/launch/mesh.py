@@ -8,22 +8,11 @@ import; smoke tests and benches see the real single device.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 
-__all__ = ["make_production_mesh", "make_mesh", "single_device_mesh", "axis_type_kwargs"]
-
-# jax >= 0.5 exposes jax.sharding.AxisType and expects axis_types=;
-# 0.4.x has neither, and jax.make_mesh rejects the kwarg there.
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-
-def axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwargs for ``jax.make_mesh``, or ``{}`` on jax 0.4.x."""
-    if _AXIS_TYPE is None:
-        return {}
-    return {"axis_types": (_AXIS_TYPE.Auto,) * n_axes}
+__all__ = ["make_production_mesh", "make_mesh", "single_device_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -31,11 +20,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    return jax.make_mesh(shape, axes, **axis_type_kwargs(len(axes)))
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD partitioning);
+    ``devices`` picks the devices (default: all of them)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def single_device_mesh(axes: Tuple[str, ...] = ("data", "model")):

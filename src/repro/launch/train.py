@@ -39,7 +39,8 @@ from ..data.pipeline import SyntheticPipeline
 from ..models.registry import build_model, get_config
 from ..optim import init_error_state
 from ..sharding.partition import make_rules
-from .mesh import make_mesh, single_device_mesh
+from .compile_cache import enable_compile_cache
+from .mesh import single_device_mesh
 from .steps import build_train_step
 
 __all__ = ["ADCCTrainer", "StragglerMonitor", "main"]
@@ -233,6 +234,7 @@ def main(argv=None) -> None:
     ap.add_argument("--grad-compression", default="none",
                     choices=["none", "int8"])
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -20,11 +20,9 @@ already holds. So:
 3. Evaluate every cell's recovery analytically from that view, with the
    numerically heavy parts — CG's invariant backward-scan and ABFT's
    per-chunk checksum verification — stacked across the *entire cell
-   batch* and dispatched as a handful of jax jit launches through
-   :mod:`repro.core.backends.batched` (on TPU a dense symmetrized-
-   operator GEMM through the Pallas kernels; elsewhere a batched
-   sparse gather matvec on XLA — see
-   :func:`~repro.core.backends.batched.cg_route`). Device error
+   batch* and dispatched as a handful of float64 XLA jit launches
+   through :mod:`repro.core.backends.batched` (the CG residuals as a
+   batched sparse gather matvec). Device error
    magnitudes are accepted only outside a 2x certainty band around
    each tolerance; borderline candidates are re-checked with the exact
    host invariant/ABFT code, keeping batched cells bit-identical to
@@ -50,10 +48,7 @@ math — exact on device, so no certainty band; flagged-bad rows are
 still re-confirmed by the exact host code).
 
 Pairs the analytic evaluators do not cover — user-registered strategy
-or workload subclasses, CG systems too large to densify on the dense
-route (:data:`~repro.core.backends.batched.GEMM_MAX_N`; the sparse
-route is ungated), or an environment without jax — fall back per-cell
-to restore + ``_measure``
+or workload subclasses — fall back per-cell to restore + ``_measure``
 (without byte-certification), so ``mode="batched"`` is always safe to
 request. Fallback cells carry the machine-readable reason in
 ``info["batched_fallback"]`` so benchmarks can assert zero fallbacks
@@ -328,40 +323,29 @@ class _CGAdccEvaluator:
         self._op = None
 
     def _operator(self):
-        """The symmetrized operator S = 0.5*(A + A^T) in the
-        representation ``cg_invariant_errors`` will route: densified
-        for the Pallas GEMM on TPU; as padded equal-width row slabs
-        (vals/cols (n, K), K the widest row, zero entries padding) for
-        the gather-only sparse matvec elsewhere. Duplicate (row, col)
-        entries are summed either way, exactly like the host's
-        ``_sym_matvec``."""
+        """The symmetrized operator S = 0.5*(A + A^T) as padded
+        equal-width row slabs (vals/cols (n, K), K the widest row, zero
+        entries padding) for ``cg_invariant_errors``' gather-only
+        sparse matvec. Duplicate (row, col) entries are summed, exactly
+        like the host's ``_sym_matvec``."""
         if self._op is None:
             A, n = self._A, self._n
             rows = np.repeat(np.arange(n, dtype=np.int64), np.diff(A.indptr))
-            if device.cg_route() == "dense":
-                # scatter-ADD via bincount: CSR rows may repeat a column
-                # index, and assignment would silently drop the
-                # duplicates' sum; bincount accumulates them like
-                # np.add.at but about an order of magnitude faster
-                dense = np.bincount(rows * n + A.indices, weights=A.data,
-                                    minlength=n * n).reshape(n, n)
-                self._op = ("dense", 0.5 * (dense + dense.T))
-            else:
-                keys = np.concatenate([rows * n + A.indices,
-                                       A.indices.astype(np.int64) * n + rows])
-                uniq, inv = np.unique(keys, return_inverse=True)
-                svals = 0.5 * np.bincount(
-                    inv, weights=np.concatenate([A.data, A.data]))
-                srows = (uniq // n).astype(np.int64)
-                counts = np.bincount(srows, minlength=n)
-                K = int(counts.max()) if len(counts) else 1
-                starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-                pos = np.arange(len(uniq)) - np.repeat(starts, counts)
-                vals2d = np.zeros((n, K))
-                cols2d = np.zeros((n, K), dtype=np.int32)
-                vals2d[srows, pos] = svals
-                cols2d[srows, pos] = (uniq % n).astype(np.int32)
-                self._op = ("sparse", vals2d, cols2d)
+            keys = np.concatenate([rows * n + A.indices,
+                                   A.indices.astype(np.int64) * n + rows])
+            uniq, inv = np.unique(keys, return_inverse=True)
+            svals = 0.5 * np.bincount(
+                inv, weights=np.concatenate([A.data, A.data]))
+            srows = (uniq // n).astype(np.int64)
+            counts = np.bincount(srows, minlength=n)
+            K = int(counts.max()) if len(counts) else 1
+            starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+            pos = np.arange(len(uniq)) - np.repeat(starts, counts)
+            vals2d = np.zeros((n, K))
+            cols2d = np.zeros((n, K), dtype=np.int32)
+            vals2d[srows, pos] = svals
+            cols2d[srows, pos] = (uniq % n).astype(np.int32)
+            self._op = (vals2d, cols2d)
         return self._op
 
     def recover_batch(self, cells: List[_BatchedCell]) -> List[RecoveryResult]:
@@ -409,7 +393,7 @@ class _CGAdccEvaluator:
                 Q[k] = st.q[j]
                 R[k] = st.r[j + 1]
                 Z[k] = st.z[j + 1]
-            orth, rel = device.cg_invariant_errors(P, Q, R, Z, b0, op)
+            orth, rel = device.cg_invariant_errors(P, Q, R, Z, b0, *op)
             nxt: List[_CGScan] = []
             for k, st in enumerate(active):
                 j = st.upper - st.tested
@@ -619,9 +603,7 @@ class _KVAdccEvaluator:
     pipeline computes the same 63-bit integer function exactly, so
     there is no certainty band; per the established discipline any row
     the device flags bad is still re-confirmed by the exact host
-    ``_row_ok`` port before it can reject a root or count a violation.
-    The kernels' host fallbacks keep this route available without jax,
-    just slower."""
+    ``_row_ok`` port before it can reject a root or count a violation."""
 
     def __init__(self, wl: KVWorkload):
         self._wl = wl
@@ -895,14 +877,7 @@ def _make_evaluator(wl: Workload, strat: ConsistencyStrategy):
     if t is AdccStrategy:
         if type(wl) is XSBenchWorkload:
             return _XSBenchEvaluator(wl), None
-        if not device.have_jax():
-            return None, "no-jax"
         if type(wl) is CGWorkload:
-            # only the dense (TPU/Pallas GEMM) route densifies the
-            # operator; the sparse route scales with nnz and is ungated
-            if (device.cg_route() == "dense"
-                    and wl._impl.A.n > device.GEMM_MAX_N):
-                return None, "cg-too-large"
             return _CGAdccEvaluator(wl), None
         if type(wl) is MMWorkload:
             return _MMAdccEvaluator(wl), None

@@ -833,6 +833,33 @@ def _check_parallelizable(workloads: Sequence, strategies: Sequence) -> None:
                 "('name' or 'name@interval'), not instances")
 
 
+def _shards_run_device_math(mode: str, cfg: Optional[NVMConfig]) -> bool:
+    """Whether a shard of this sweep launches jax: the batched
+    evaluators do, and so does the ``device`` emulator backend's
+    forward pass in every mode."""
+    backend = (cfg if cfg is not None else NVMConfig()).backend
+    return mode == "batched" or backend == "device"
+
+
+def _check_one_process_per_chip(mode: str, cfg: Optional[NVMConfig],
+                                workers: int) -> None:
+    """workers>1 runs each shard in its own process. A chip belongs to
+    one process at a time, so when the shards would run device math and
+    this process's default JAX backend is an accelerator, the children
+    could neither share the chip nor fall back to the CPU without saying
+    so: refuse up front."""
+    if not _shards_run_device_math(mode, cfg):
+        return
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise ValueError(
+            f"sweep(workers={workers}) would run device math (batched "
+            f"mode or the device backend) in {workers} worker processes, "
+            f"but the default JAX backend is {platform!r} and a chip "
+            "serves one process: use workers=1 on an accelerator")
+
+
 def _validate_sweep_specs(workloads: Sequence, strategies: Sequence) -> None:
     """Fail a typo'd matrix up front in the parent — with the registered
     names and a closest-match suggestion — instead of a bare KeyError
@@ -916,6 +943,8 @@ def sweep(workloads: Sequence = ("cg", "mm", "xsbench"),
     ``workers=N`` shards the (workload, strategy) pairs across N
     supervised processes (pairs are independent; snapshots are
     per-emulator) and merges results in deterministic pair-major order,
+    and is refused where the shards would run device math on an
+    accelerator (one process per chip: ``_check_one_process_per_chip``),
     so the cell list is identical to ``workers=1`` regardless of
     completion order. Requires picklable registry specs. ``progress``
     then fires per pair (in merge order) instead of per cell. When
@@ -991,7 +1020,7 @@ def sweep(workloads: Sequence = ("cg", "mm", "xsbench"),
         # uniform contract: the spec requirement holds whenever sharding
         # was REQUESTED, even if a single-pair matrix ends up serial
         _check_parallelizable(workloads, strategies)
-    if workers > 1:
+        _check_one_process_per_chip(mode, cfg, workers)
         import multiprocessing as mp
 
         from .pool import run_sharded
@@ -1000,11 +1029,10 @@ def sweep(workloads: Sequence = ("cg", "mm", "xsbench"),
         # forking after this process has instantiated an XLA backend
         # deadlocks the children's device math (inherited locks whose
         # owner threads don't survive the fork), e.g. a serial batched
-        # sweep followed by a sharded one. Checked for EVERY mode:
-        # batched children launch jit evaluators, and with
-        # REPRO_NVM_BACKEND=device even plain measure/full children run
-        # device math inside the emulator forward pass.
-        if jax_runtime_live():
+        # sweep followed by a sharded one. Children that run no device
+        # math keep the fork start: a spawned child re-imports jax and
+        # the whole package, seconds per attempt under a shard timeout
+        if _shards_run_device_math(mode, cfg) and jax_runtime_live():
             start = "spawn"
         if shard_timeout is None:
             shard_timeout = float(

@@ -25,18 +25,6 @@ from jax.sharding import PartitionSpec as P
 __all__ = ["pipeline_apply", "stage_params"]
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with replication checks off, on both API generations
-    (jax >= 0.5 top-level fn / check_vma, 0.4.x experimental / check_rep)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_experimental
-    return sm_experimental(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
-
-
 def stage_params(params_stacked, n_stages: int):
     """Reshape (L, ...) stacked layer params into (n_stages, L/n_stages, ...)
     per-stage groups."""
@@ -105,5 +93,6 @@ def pipeline_apply(stage_fn: Callable, params_staged, x_micro: jax.Array,
         jax.tree.map(lambda _: P(axis), params_staged),
         P(),
     )
-    return _shard_map(shard_body, mesh=mesh, in_specs=in_specs,
-                      out_specs=P())(params_staged, x_micro)
+    return jax.shard_map(shard_body, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)(params_staged,
+                                                         x_micro)

@@ -13,9 +13,11 @@ Covers:
     same crash image); at fraction 0.0 word mode is bit-identical to
     the bare all-or-nothing crash — on the shared selection routines,
     on both emulator backends, and through the sweep;
-  * device-math kernels — ``gemm_batch``/``tile_sums_batch`` Pallas
-    (interpret=True) vs jnp oracles, and ``cg_invariant_errors`` /
-    ``mm_chunk_stats`` dense-vs-sparse-route and vs numpy oracles.
+  * device-math launches — ``cg_invariant_errors`` / ``mm_chunk_stats``
+    / the KV and cache-op kernels vs numpy and host oracles, across
+    chunked launches, with the per-kernel launch counter;
+  * coverage — the figure matrices' device-math pairs take the analytic
+    route with zero ``batched_fallback`` cells.
 """
 
 import numpy as np
@@ -27,11 +29,11 @@ from repro.core.backends.base import (
     select_survivor_words,
     word_spans,
 )
+from repro.core.backends import batched
 from repro.core.backends.batched import (
+    LAUNCHES,
     cache_op_update,
     cg_invariant_errors,
-    cg_route,
-    have_jax,
     kv_row_checksums,
     kv_value_match,
     mm_chunk_stats,
@@ -326,11 +328,6 @@ class TestWordGranularityRefinement:
 # device math vs oracles
 # ---------------------------------------------------------------------------
 
-pytestmark_jax = pytest.mark.skipif(not have_jax(),
-                                    reason="jax unavailable")
-
-
-@pytestmark_jax
 class TestBatchedDeviceMath:
     def _cg_batch(self, seed, T=5, n=24):
         rng = np.random.default_rng(seed)
@@ -340,43 +337,55 @@ class TestBatchedDeviceMath:
         S = 0.5 * (S + S.T)
         return P, Q, R, Z, b, S
 
-    def _sparse_of(self, S):
+    def _slabs_of(self, S):
         # dense matrix as full-width slabs: every column is a "nonzero"
         n = S.shape[0]
         cols = np.tile(np.arange(n, dtype=np.int32), (n, 1))
-        return "sparse", S.copy(), cols
+        return S.copy(), cols
 
-    def test_cg_errors_match_numpy_oracle_both_routes(self):
+    def _cg_oracle(self, P, Q, R, Z, b, S):
+        orth = (np.abs(np.sum(P * Q, axis=1))
+                / (np.linalg.norm(P, axis=1)
+                   * np.linalg.norm(Q, axis=1) + 1e-300))
+        rel = (np.linalg.norm(R - (b[None, :] - Z @ S), axis=1)
+               / (np.linalg.norm(b) + 1e-300))
+        return orth, rel
+
+    def test_cg_errors_match_numpy_oracle(self):
         P, Q, R, Z, b, S = self._cg_batch(0)
-        want_orth = (np.abs(np.sum(P * Q, axis=1))
-                     / (np.linalg.norm(P, axis=1)
-                        * np.linalg.norm(Q, axis=1) + 1e-300))
-        want_rel = (np.linalg.norm(R - (b[None, :] - Z @ S), axis=1)
-                    / (np.linalg.norm(b) + 1e-300))
-        for op in (("dense", S), self._sparse_of(S)):
-            orth, rel = cg_invariant_errors(P, Q, R, Z, b, op,
-                                            use_pallas=False)
-            np.testing.assert_allclose(orth, want_orth, rtol=1e-12)
-            np.testing.assert_allclose(rel, want_rel, rtol=1e-10)
+        want_orth, want_rel = self._cg_oracle(P, Q, R, Z, b, S)
+        orth, rel = cg_invariant_errors(P, Q, R, Z, b, *self._slabs_of(S))
+        np.testing.assert_allclose(orth, want_orth, rtol=1e-12)
+        np.testing.assert_allclose(rel, want_rel, rtol=1e-10)
 
-    def test_cg_errors_dense_route_through_pallas_interpret(self):
-        P, Q, R, Z, b, S = self._cg_batch(1, T=3, n=16)
-        xla = cg_invariant_errors(P, Q, R, Z, b, ("dense", S),
-                                  use_pallas=False)
-        pal = cg_invariant_errors(P, Q, R, Z, b, ("dense", S),
-                                  use_pallas=True, interpret=True)
-        for a, p in zip(xla, pal):
-            np.testing.assert_allclose(p, a, rtol=1e-9)
+    def test_cg_errors_across_chunked_launches(self):
+        # more rows than one launch holds: every chunk is padded to the
+        # fixed launch width and the results stitch back in order
+        P, Q, R, Z, b, S = self._cg_batch(1, T=batched.SPARSE_BLOCK_ROWS + 7,
+                                          n=16)
+        want_orth, want_rel = self._cg_oracle(P, Q, R, Z, b, S)
+        before = LAUNCHES[("cg_errors", "cpu")]
+        orth, rel = cg_invariant_errors(P, Q, R, Z, b, *self._slabs_of(S))
+        assert LAUNCHES[("cg_errors", "cpu")] - before == 2
+        np.testing.assert_allclose(orth, want_orth, rtol=1e-12)
+        np.testing.assert_allclose(rel, want_rel, rtol=1e-10)
 
-    def test_cg_errors_unknown_operator_kind_raises(self):
-        P, Q, R, Z, b, S = self._cg_batch(2, T=2, n=8)
-        with pytest.raises(ValueError):
-            cg_invariant_errors(P, Q, R, Z, b, ("csr", S))
+    def test_cg_operator_slabs_sum_duplicate_entries(self):
+        # CSR rows may repeat a column; the slab operator must sum them
+        # exactly like the host's scatter-add _sym_matvec
+        from repro.algorithms.cg import CsrMatrix, _sym_matvec
+        from repro.scenarios.batched_engine import _CGAdccEvaluator
 
-    def test_cg_route_spellings(self):
-        assert cg_route(use_pallas=True) == "dense"
-        assert cg_route(use_pallas=False) == "sparse"
-        assert cg_route() in ("dense", "sparse")
+        indptr = np.array([0, 3, 4, 6, 7])
+        indices = np.array([0, 2, 2, 1, 3, 3, 0], dtype=np.int32)
+        data = np.array([4.0, 1.0, 0.5, 3.0, 2.0, -1.0, 0.25])
+        A = CsrMatrix(n=4, indptr=indptr, indices=indices, data=data)
+        ev = _CGAdccEvaluator.__new__(_CGAdccEvaluator)
+        ev._A, ev._n, ev._op = A, 4, None
+        vals, cols = ev._operator()
+        x = np.array([1.0, -2.0, 0.5, 3.0])
+        np.testing.assert_allclose(np.sum(vals * x[cols], axis=1),
+                                   _sym_matvec(A, x), rtol=1e-15)
 
     def _mm_batch(self, seed, B=4, m=17):
         rng = np.random.default_rng(seed)
@@ -386,13 +395,18 @@ class TestBatchedDeviceMath:
         V[:, -1, :-1] = V[:, :-1, :-1].sum(axis=1)
         return V
 
-    @pytest.mark.parametrize("use_pallas", [False, True])
-    def test_mm_stats_match_numpy_oracle(self, use_pallas):
+    @pytest.mark.parametrize("chunk_elems", [None, 17 * 17])
+    def test_mm_stats_match_numpy_oracle(self, chunk_elems, monkeypatch):
+        # chunk_elems=17*17 forces one slab per launch
+        if chunk_elems is not None:
+            monkeypatch.setattr(batched, "CHUNK_ELEMS", chunk_elems)
         V = self._mm_batch(3)
         V[1] = 0.0                    # an all-lost chunk image
         V[2, 4, 5] += 7.5             # one corrupted element
-        nonzero, absmax, rowmax, colmax = mm_chunk_stats(
-            V, use_pallas=use_pallas, interpret=use_pallas)
+        before = LAUNCHES[("mm_stats", "cpu")]
+        nonzero, absmax, rowmax, colmax = mm_chunk_stats(V)
+        assert LAUNCHES[("mm_stats", "cpu")] - before == \
+            (1 if chunk_elems is None else len(V))
         np.testing.assert_array_equal(nonzero, V.any(axis=(1, 2)))
         np.testing.assert_allclose(absmax, np.abs(V).max(axis=(1, 2)))
         want_row = np.abs(V[:, :-1, -1]
@@ -403,18 +417,6 @@ class TestBatchedDeviceMath:
         np.testing.assert_allclose(colmax, want_col, atol=1e-9)
         # intact slabs have ~0 residual; the corrupted one stands out
         assert rowmax[0] < 1e-9 and rowmax[2] > 1.0
-
-    def test_gemm_batch_pallas_interpret_matches_jnp(self):
-        import jax.numpy as jnp
-        from repro.kernels.abft_matmul.ops import gemm_batch
-
-        rng = np.random.default_rng(4)
-        a = jnp.asarray(rng.normal(size=(9, 33)), jnp.float32)
-        b = jnp.asarray(rng.normal(size=(33, 21)), jnp.float32)
-        got = gemm_batch(a, b, acc_dtype=jnp.float32,
-                         use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(a @ b),
-                                   rtol=1e-4, atol=1e-4)
 
     def test_kv_row_checksums_match_host_mixer(self):
         from repro.scenarios.kv import _mix_words
@@ -480,20 +482,27 @@ class TestBatchedDeviceMath:
         np.testing.assert_array_equal(valid, want_valid)
         np.testing.assert_array_equal(wts, np.where(want_valid, 3, 0))
 
-    def test_tile_sums_batch_pallas_interpret_matches_jnp(self):
-        import jax.numpy as jnp
-        from jax.experimental import enable_x64
-        from repro.kernels.checksum_verify.ops import tile_sums_batch
 
-        rng = np.random.default_rng(5)
-        x = jnp.asarray(rng.normal(size=(3, 18, 26)), jnp.float32)
-        # f64 accumulation needs the x64 context the engine runs under
-        with enable_x64():
-            rows, cols = tile_sums_batch(x, acc_dtype=jnp.float64,
-                                         use_pallas=True, interpret=True)
-        np.testing.assert_allclose(np.asarray(rows),
-                                   np.asarray(x, np.float64).sum(2),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(cols),
-                                   np.asarray(x, np.float64).sum(1),
-                                   rtol=1e-5, atol=1e-5)
+class TestFigureMatricesTakeTheDevicePath:
+    """The figure matrices whose evaluators launch device math — fig_torn
+    (cg, adcc) / (mm, adcc) and fig_kv's adcc profiles — must evaluate
+    every crashed cell analytically: zero ``batched_fallback`` cells,
+    and a launch of each kernel they lean on."""
+
+    @pytest.mark.parametrize("figure", ["fig_torn", "fig_kv"])
+    def test_smoke_matrix_has_no_fallback_cells(self, figure):
+        import importlib
+
+        fig = importlib.import_module(f"benchmarks.{figure}")
+        kw = fig._sweep_kw(smoke=True)
+        wls = (kw["workloads"][:2] if figure == "fig_torn"
+               else kw["workloads"])
+        kernels = (("cg_errors", "mm_stats") if figure == "fig_torn"
+                   else ("kv_row_checksums", "kv_value_match"))
+        before = {k: LAUNCHES[(k, "cpu")] for k in kernels}
+        cells = sweep(workloads=wls, strategies=("adcc",), plans=kw["plans"],
+                      cfg=kw["cfg"], engine="fork", mode="batched")
+        assert cells
+        assert [c for c in cells if "batched_fallback" in c.info] == []
+        for k in kernels:
+            assert LAUNCHES[(k, "cpu")] > before[k], k

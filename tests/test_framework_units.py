@@ -151,3 +151,48 @@ class TestShardAct:
         mesh = single_device_mesh()
         x = jnp.ones((1, 8, 16))   # batch 1 still divisible by 1 -> applied
         assert shard_act(x, mesh).shape == x.shape
+
+
+class TestCompileCache:
+    """``enable_compile_cache``: JAX_COMPILATION_CACHE_DIR wins when set;
+    otherwise the cache sits at the checkout's fixed ``.jax_cache``."""
+
+    def test_unset_env_points_at_checkout(self, monkeypatch):
+        import os
+
+        from repro.launch.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        was = jax.config.jax_compilation_cache_dir
+        try:
+            got = enable_compile_cache()
+            assert got == os.path.join(root, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        finally:
+            jax.config.update("jax_compilation_cache_dir", was)
+        with open(os.path.join(root, ".gitignore")) as fh:
+            assert ".jax_cache/" in fh.read().split()
+
+    def test_set_env_is_left_to_jax_and_cache_lands_there(self, tmp_path):
+        import os
+        import subprocess
+        import sys
+
+        code = (
+            "import jax, jax.numpy as jnp\n"
+            "from repro.launch.compile_cache import enable_compile_cache\n"
+            "jax.config.update('jax_persistent_cache_min_compile_time_secs',"
+            " 0)\n"
+            "got = enable_compile_cache()\n"
+            "assert got == jax.config.jax_compilation_cache_dir, got\n"
+            "print(got)\n"
+            "jax.jit(lambda x: jnp.sin(x) * 3)(jnp.ones(8)).block_until_ready()\n")
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = dict(os.environ, JAX_PLATFORMS="cpu",
+                   JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cc"),
+                   PYTHONPATH=os.path.join(root, "src"))
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip() == str(tmp_path / "cc")
+        assert os.listdir(tmp_path / "cc")

@@ -527,6 +527,33 @@ class TestSweepInvariance:
         with pytest.raises(ValueError):
             sweep(workloads=(CG,), strategies=("none",), workers=0)
 
+    @pytest.mark.parametrize("mode,backend", [("batched", "vectorized"),
+                                              ("measure", "device")])
+    def test_device_math_shards_refused_on_an_accelerator(
+            self, monkeypatch, mode, backend):
+        # one process per chip: shards that would run device math may
+        # neither share the parent's chip nor drop to the CPU unsaid
+        import jax
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pytest.raises(ValueError, match="one process"):
+            sweep(workloads=(CG, MM), strategies=("none", "adcc"),
+                  plans=(CrashPlan.no_crash(),), mode=mode, workers=2,
+                  cfg=NVMConfig(cache_bytes=512 * 1024, backend=backend))
+
+    def test_host_only_shards_allowed_on_an_accelerator(self, monkeypatch):
+        # measure cells on the vectorized backend run no device math:
+        # their shards never ask which platform the parent defaults to
+        import jax
+
+        def no_backend_query():
+            raise AssertionError("host-only sweep queried the JAX backend")
+
+        monkeypatch.setattr(jax, "default_backend", no_backend_query)
+        cells = sweep(workloads=(CG, MM), strategies=("none",),
+                      plans=(CrashPlan.no_crash(),), cfg=SMALL,
+                      mode="measure", workers=2)
+        assert len(cells) == 2
+
 
 class TestCostModel:
     def test_seven_mechanism_axis(self):
