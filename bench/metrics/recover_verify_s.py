@@ -1,0 +1,30 @@
+"""Recovery's checks of the slots it read (the program's
+``adcc.recover.verify`` spans: ``unflatten_state`` and
+``verify_state_against_record``, which puts every leaf on the device and
+pulls its sum), summed per restart cycle, mean over the window's cycles.
+
+Read from a traced run. The window's cycles are the last
+``len(restore_s)`` ``adcc.recover`` roots of the program's record (the
+warm-up cycle's root comes before them); a check belongs to the root
+whose ``restart`` ordinal it carries. A program without these spans
+reads nothing."""
+
+import statistics
+
+
+def read(obs):
+    n = len(obs.get("restore_s") or ())
+    if not obs.get("trace") or not n:
+        return None
+    try:
+        from repro.tracing import spans
+    except ImportError:
+        return None
+    roots = sorted(spans("adcc.recover"), key=lambda s: s.start_ns)[-n:]
+    checks = spans("adcc.recover.verify")
+    per = [[s.seconds for s in checks
+            if s.attrs.get("restart") == r.attrs.get("restart")]
+           for r in roots]
+    if len(roots) < n or not all(per):
+        return None
+    return statistics.fmean(sum(p) for p in per)

@@ -17,7 +17,6 @@ writes every suite's rows as one machine-readable artifact.
   fig_kv    KV serving durability vs overhead matrix  (BENCH_kv.json)
   scenarios workload x strategy x crash-point sweep   (BENCH_scenarios.json)
   sweep     rerun/fork/measure sweep timing + gates   (BENCH_sweep.json)
-  train     training-loop ADCC vs sync checkpoint     (beyond-paper)
   kernel    ABFT matmul fused-checksum overhead       (kernel-level)
 
 Suites construct their NVMConfigs lazily (inside ``run()``), so
@@ -44,7 +43,7 @@ from repro.launch.compile_cache import enable_compile_cache
 from . import (fig3_cg_recompute, fig4_cg_runtime, fig7_mm_recompute,
                fig8_mm_runtime, fig10_12_mc_correctness, fig13_mc_runtime,
                fig_faults, fig_kv, fig_torn, kernel_bench, scenarios_sweep,
-               sweep_timing, train_overhead)
+               sweep_timing)
 from .common import emit, rows_to_records, write_json
 
 SUITES = {
@@ -59,7 +58,6 @@ SUITES = {
     "fig_kv": fig_kv,
     "scenarios": scenarios_sweep,
     "sweep": sweep_timing,
-    "train": train_overhead,
     "kernel": kernel_bench,
 }
 SUITE_NAMES = tuple(SUITES)

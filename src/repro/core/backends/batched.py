@@ -33,13 +33,14 @@ launch by kernel and by the platform its result landed on.
 
 from __future__ import annotations
 
-import collections
 import functools
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ... import tracing
 
 __all__ = ["jax_runtime_live", "LAUNCHES",
            "cg_invariant_errors", "mm_chunk_stats",
@@ -56,8 +57,9 @@ CHUNK_ELEMS = 1 << 25
 # batch/wave sizes vary
 SPARSE_BLOCK_ROWS = 256
 
-# (kernel name, platform of its result) -> launches in this process
-LAUNCHES: collections.Counter = collections.Counter()
+# (kernel name, platform of its result) -> launches in this process, kept
+# in the program's counters (repro.tracing)
+LAUNCHES = tracing.counter_group("batched.launches")
 
 
 def _count(kernel: str, out: jax.Array) -> None:

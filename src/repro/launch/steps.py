@@ -95,12 +95,16 @@ def build_train_step(api: ModelApi, tcfg: TrainConfig,
             return api.loss_fn(jax.tree.map(to_compute, p), batch, mesh,
                                remat=tcfg.remat)
 
-        loss, grads = jax.value_and_grad(loss_of)(params)
-        if use_compression:
-            grads, err_state = compress_decompress(grads, err_state, rng)
-        updates, new_opt_state = opt_update(grads, opt_state, params)
-        new_params = jax.tree.map(
-            lambda p, u: p + u.astype(p.dtype), params, updates)
+        # stable names for the device's operations, whatever the HLO
+        # numbering: model, optimizer, adcc.checksums
+        with jax.named_scope("model"):
+            loss, grads = jax.value_and_grad(loss_of)(params)
+        with jax.named_scope("optimizer"):
+            if use_compression:
+                grads, err_state = compress_decompress(grads, err_state, rng)
+            updates, new_opt_state = opt_update(grads, opt_state, params)
+            new_params = jax.tree.map(
+                lambda p, u: p + u.astype(p.dtype), params, updates)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                              for g in jax.tree.leaves(grads)))
         metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm}
@@ -109,11 +113,12 @@ def build_train_step(api: ModelApi, tcfg: TrainConfig,
         # the update sums additionally give the *linearity chain*
         # cks_params[t] == cks_params[t-1] + cks_updates[t] used to verify
         # the ledger itself (core/acc_state.py).
-        checksums = {
-            "params": tree_checksums(new_params),
-            "opt": tree_checksums(new_opt_state),
-            "updates": tree_checksums(updates),
-        }
+        with jax.named_scope("adcc.checksums"):
+            checksums = {
+                "params": tree_checksums(new_params),
+                "opt": tree_checksums(new_opt_state),
+                "updates": tree_checksums(updates),
+            }
         return new_params, new_opt_state, err_state, metrics, checksums
 
     # --- shardings -----------------------------------------------------------

@@ -16,20 +16,29 @@ crash/restart integration test.
 
 Also includes the step-time straggler monitor (flags slow hosts for the
 controller to replace — simulated single-host here, interface real).
+
+Each step is a ``train`` span root (repro.tracing) around the whole
+iteration, with the step function's call as its ``train.dispatch``
+child; the root's duration is the step time the monitor, the log line
+and ``TrainerResult.step_seconds`` take. Recovery is an
+``adcc.recover`` span carrying the process's restart ordinal, with an
+``adcc.recover.read`` and an ``adcc.recover.verify`` child per slot it
+reads and checks.
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import itertools
 import os
-import time
 from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import tracing
 from ..configs.base import ModelConfig, TrainConfig
 from ..core.acc_state import (ChecksumLedger, LedgerRecord, flatten_checksums,
                               verify_state_against_record)
@@ -44,6 +53,10 @@ from .mesh import single_device_mesh
 from .steps import build_train_step
 
 __all__ = ["ADCCTrainer", "StragglerMonitor", "main"]
+
+# the restart ordinal of each recovery in this process, which its
+# adcc.recover span and their children carry
+_RESTARTS = itertools.count(1)
 
 
 class StragglerMonitor:
@@ -109,31 +122,35 @@ class ADCCTrainer:
     # -- recovery ---------------------------------------------------------------
     def _try_recover(self):
         """-> (params, opt_state, resume_step, report) or Nones."""
-        recs = {r.step: r for r in self.ledger.validated_records()}
-        if not recs:
-            return None, None, 0, "no ledger"
-        template_p, _ = self.api.abstract_init(jax.random.PRNGKey(0))
-        for slot, step in self.store.slots_by_recency():
-            rec = recs.get(step)
-            if rec is None:
-                continue
-            flat = self.store.read_slot(slot)
-            if flat is None:
-                continue
-            try:
-                state = unflatten_state(
-                    {"params": template_p,
-                     "opt": jax.eval_shape(self.opt_init, template_p)}, flat)
-            except (KeyError, ValueError):
-                continue  # torn slot: missing/short leaves
-            ok, bad = verify_state_against_record(
-                state["params"], state["opt"], rec)
-            if ok:
-                return (state["params"], state["opt"], step + 1,
-                        f"slot {slot} @ step {step} verified")
-        newest = max(recs)
-        return None, None, 0, (f"no slot verified (ledger reaches step "
-                               f"{newest}); restart from scratch")
+        with tracing.span("adcc.recover", restart=next(_RESTARTS)):
+            recs = {r.step: r for r in self.ledger.validated_records()}
+            if not recs:
+                return None, None, 0, "no ledger"
+            template_p, _ = self.api.abstract_init(jax.random.PRNGKey(0))
+            for slot, step in self.store.slots_by_recency():
+                rec = recs.get(step)
+                if rec is None:
+                    continue
+                with tracing.span("adcc.recover.read", slot=slot):
+                    flat = self.store.read_slot(slot)
+                if flat is None:
+                    continue
+                with tracing.span("adcc.recover.verify", slot=slot):
+                    try:
+                        state = unflatten_state(
+                            {"params": template_p,
+                             "opt": jax.eval_shape(self.opt_init,
+                                                   template_p)}, flat)
+                    except (KeyError, ValueError):
+                        continue  # torn slot: missing/short leaves
+                    ok, bad = verify_state_against_record(
+                        state["params"], state["opt"], rec)
+                if ok:
+                    return (state["params"], state["opt"], step + 1,
+                            f"slot {slot} @ step {step} verified")
+            newest = max(recs)
+            return None, None, 0, (f"no slot verified (ledger reaches step "
+                                   f"{newest}); restart from scratch")
 
     # -- main loop ------------------------------------------------------------------
     def run(self, steps: int, crash_at_step: Optional[int] = None,
@@ -152,47 +169,47 @@ class ADCCTrainer:
         times: List[float] = []
         t = start
         while t < steps:
-            t0 = time.perf_counter()
-            batch = {k: jnp.asarray(v)
-                     for k, v in self.pipeline.batch_at(t).items()}
-            rng = jax.random.fold_in(jax.random.PRNGKey(self.tcfg.seed), t)
-            params, opt_state, err_state, metrics, cks = self.step_fn(
-                params, opt_state, err_state, batch, rng)
-            loss = float(metrics["loss"])
-            losses.append(loss)
+            with tracing.step("train", t) as root:
+                batch = {k: jnp.asarray(v)
+                         for k, v in self.pipeline.batch_at(t).items()}
+                rng = jax.random.fold_in(jax.random.PRNGKey(self.tcfg.seed), t)
+                with tracing.span("train.dispatch"):
+                    params, opt_state, err_state, metrics, cks = self.step_fn(
+                        params, opt_state, err_state, batch, rng)
+                loss = float(metrics["loss"])
+                losses.append(loss)
 
-            # (3) synchronous tiny ledger write — the "one cache line"
-            if self.mode == "adcc":
-                self.ledger.append(LedgerRecord(
-                    step=t, rng_seed=self.tcfg.seed,
-                    cursor=[self.tcfg.seed, t + 1, 0],
-                    cks_params=flatten_checksums(cks["params"]),
-                    cks_opt=flatten_checksums(cks["opt"]),
-                    cks_updates=flatten_checksums(cks["updates"]),
-                    loss=loss))
-                # (4) async fence-free heavy-state write
-                if (t + 1) % self.slot_every == 0:
-                    self.writer.submit(t, flatten_state(
-                        {"params": params, "opt": opt_state}))
-            elif self.mode == "sync" and (t + 1) % self.slot_every == 0:
-                # traditional checkpoint: blocking full copy + ledger
-                self.ledger.append(LedgerRecord(
-                    step=t, rng_seed=self.tcfg.seed,
-                    cursor=[self.tcfg.seed, t + 1, 0],
-                    cks_params=flatten_checksums(cks["params"]),
-                    cks_opt=flatten_checksums(cks["opt"]),
-                    cks_updates=flatten_checksums(cks["updates"]),
-                    loss=loss))
-                self.store.write_slot(
-                    self.store.slot_for_step((t + 1) // self.slot_every),
-                    t, flatten_state({"params": params, "opt": opt_state}))
+                # (3) synchronous tiny ledger write — the "one cache line"
+                if self.mode == "adcc":
+                    self.ledger.append(LedgerRecord(
+                        step=t, rng_seed=self.tcfg.seed,
+                        cursor=[self.tcfg.seed, t + 1, 0],
+                        cks_params=flatten_checksums(cks["params"]),
+                        cks_opt=flatten_checksums(cks["opt"]),
+                        cks_updates=flatten_checksums(cks["updates"]),
+                        loss=loss))
+                    # (4) async fence-free heavy-state write
+                    if (t + 1) % self.slot_every == 0:
+                        self.writer.submit(t, flatten_state(
+                            {"params": params, "opt": opt_state}))
+                elif self.mode == "sync" and (t + 1) % self.slot_every == 0:
+                    # traditional checkpoint: blocking full copy + ledger
+                    self.ledger.append(LedgerRecord(
+                        step=t, rng_seed=self.tcfg.seed,
+                        cursor=[self.tcfg.seed, t + 1, 0],
+                        cks_params=flatten_checksums(cks["params"]),
+                        cks_opt=flatten_checksums(cks["opt"]),
+                        cks_updates=flatten_checksums(cks["updates"]),
+                        loss=loss))
+                    self.store.write_slot(
+                        self.store.slot_for_step((t + 1) // self.slot_every),
+                        t, flatten_state({"params": params, "opt": opt_state}))
 
-            dt_step = time.perf_counter() - t0
-            times.append(dt_step)
-            self.monitor.record(t, dt_step)
+            times.append(root.seconds)
+            self.monitor.record(t, root.seconds)
             if log_every and t % log_every == 0:
                 print(f"step {t:5d} loss {loss:.4f} "
-                      f"({dt_step*1e3:.0f} ms)", flush=True)
+                      f"({root.seconds*1e3:.0f} ms)", flush=True)
 
             if crash_at_step is not None and t == crash_at_step:
                 self.crash()
