@@ -22,7 +22,8 @@ analogue persists a few-KB *ledger record* synchronously each step —
 
 Records are single JSON lines; a torn append produces an unparsable or
 chain-breaking tail line, which recovery skips — by construction the
-ledger needs no fsync ordering with the slots.
+ledger needs no fsync ordering with the slots. A record's loss and
+checksums come to the host in one transfer (``fetch_record_values``).
 """
 
 from __future__ import annotations
@@ -32,17 +33,39 @@ import json
 import os
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["LedgerRecord", "ChecksumLedger", "flatten_checksums",
+__all__ = ["LedgerRecord", "ChecksumLedger", "fetch_record_values",
            "verify_state_against_record"]
 
+# the checksum groups of a record, in the order the record vector holds them
+_CHECKSUM_GROUPS = ("params", "opt", "updates")
 
-def flatten_checksums(tree) -> List[float]:
-    """Deterministic (sorted-path) flattening of a checksum pytree."""
-    import jax
-    leaves = jax.tree.leaves(tree)
-    return [float(x) for x in leaves]
+
+@jax.jit
+def _record_vector(loss, cks):
+    """The loss, then every checksum in leaf order of each group, as one
+    f32 vector on the device."""
+    leaves = [loss] + [x for g in _CHECKSUM_GROUPS
+                       for x in jax.tree.leaves(cks[g])]
+    return jnp.stack([jnp.asarray(x, jnp.float32) for x in leaves])
+
+
+def fetch_record_values(loss, cks) -> Dict[str, object]:
+    """A record's ``loss``, ``cks_params``, ``cks_opt`` and
+    ``cks_updates`` from the step's f32 scalars, brought to the host in
+    one transfer. Each value is the scalar widened to a Python float,
+    as ``float`` of it gives."""
+    values = jax.device_get(_record_vector(loss, cks)).tolist()
+    out: Dict[str, object] = {"loss": values[0]}
+    at = 1
+    for g in _CHECKSUM_GROUPS:
+        n = len(jax.tree.leaves(cks[g]))
+        out["cks_" + g] = values[at:at + n]
+        at += n
+    return out
 
 
 @dataclasses.dataclass

@@ -4,7 +4,8 @@ Per step the trainer:
   1. pulls batch t from the counter-based pipeline (pure function of t),
   2. runs the jitted train_step (params/opt sharded per partition rules),
   3. synchronously appends the few-KB checksum ledger record — the
-     paper's "flush one cache line per iteration",
+     paper's "flush one cache line per iteration" — its loss and
+     checksums fetched from the device in one transfer,
   4. every ``slot_every`` steps enqueues the heavy state to the async,
      fence-free slot writer (torn on crash, like cache-eviction residue).
 
@@ -40,7 +41,8 @@ import numpy as np
 
 from .. import tracing
 from ..configs.base import ModelConfig, TrainConfig
-from ..core.acc_state import (ChecksumLedger, LedgerRecord, flatten_checksums,
+from ..core.acc_state import (ChecksumLedger, LedgerRecord,
+                              fetch_record_values,
                               verify_state_against_record)
 from ..core.slots import (AsyncSlotWriter, SlotStore, flatten_state,
                           unflatten_state)
@@ -176,31 +178,26 @@ class ADCCTrainer:
                 with tracing.span("train.dispatch"):
                     params, opt_state, err_state, metrics, cks = self.step_fn(
                         params, opt_state, err_state, batch, rng)
-                loss = float(metrics["loss"])
+                # (3) synchronous tiny ledger write — the "one cache line";
+                # 'sync' writes one with each blocking checkpoint
+                boundary = (t + 1) % self.slot_every == 0
+                if self.mode == "adcc" or (self.mode == "sync" and boundary):
+                    rec = LedgerRecord(
+                        step=t, rng_seed=self.tcfg.seed,
+                        cursor=[self.tcfg.seed, t + 1, 0],
+                        **fetch_record_values(metrics["loss"], cks))
+                    self.ledger.append(rec)
+                    loss = rec.loss
+                else:
+                    loss = float(metrics["loss"])
                 losses.append(loss)
 
-                # (3) synchronous tiny ledger write — the "one cache line"
-                if self.mode == "adcc":
-                    self.ledger.append(LedgerRecord(
-                        step=t, rng_seed=self.tcfg.seed,
-                        cursor=[self.tcfg.seed, t + 1, 0],
-                        cks_params=flatten_checksums(cks["params"]),
-                        cks_opt=flatten_checksums(cks["opt"]),
-                        cks_updates=flatten_checksums(cks["updates"]),
-                        loss=loss))
+                if self.mode == "adcc" and boundary:
                     # (4) async fence-free heavy-state write
-                    if (t + 1) % self.slot_every == 0:
-                        self.writer.submit(t, flatten_state(
-                            {"params": params, "opt": opt_state}))
-                elif self.mode == "sync" and (t + 1) % self.slot_every == 0:
-                    # traditional checkpoint: blocking full copy + ledger
-                    self.ledger.append(LedgerRecord(
-                        step=t, rng_seed=self.tcfg.seed,
-                        cursor=[self.tcfg.seed, t + 1, 0],
-                        cks_params=flatten_checksums(cks["params"]),
-                        cks_opt=flatten_checksums(cks["opt"]),
-                        cks_updates=flatten_checksums(cks["updates"]),
-                        loss=loss))
+                    self.writer.submit(t, flatten_state(
+                        {"params": params, "opt": opt_state}))
+                elif self.mode == "sync" and boundary:
+                    # traditional checkpoint: blocking full copy
                     self.store.write_slot(
                         self.store.slot_for_step((t + 1) // self.slot_every),
                         t, flatten_state({"params": params, "opt": opt_state}))

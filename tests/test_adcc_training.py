@@ -12,6 +12,7 @@ import pytest
 
 from repro.configs.base import TrainConfig
 from repro.core.acc_state import (ChecksumLedger, LedgerRecord,
+                                  fetch_record_values,
                                   verify_state_against_record)
 from repro.core.slots import SlotStore, flatten_state, unflatten_state
 from repro.launch.train import ADCCTrainer, StragglerMonitor
@@ -147,6 +148,134 @@ class TestCrashRestart:
         tr2 = tiny_trainer(wd, mode="sync", slot_every=4)
         r2 = tr2.run(16, log_every=0)
         assert r2.resumed_from is not None
+
+
+class _Tap:
+    """Keeps the loss and checksums that each step hands the trainer."""
+
+    def __init__(self, fn):
+        self.fn, self.outs = fn, []
+
+    def __call__(self, *args):
+        out = self.fn(*args)
+        self.outs.append((out[3]["loss"], out[4]))
+        return out
+
+
+def _per_leaf_line(t, seed, loss, cks):
+    """A record's line as one blocking ``float`` per scalar makes it."""
+    return LedgerRecord(
+        step=t, rng_seed=seed, cursor=[seed, t + 1, 0],
+        cks_params=[float(x) for x in jax.tree.leaves(cks["params"])],
+        cks_opt=[float(x) for x in jax.tree.leaves(cks["opt"])],
+        cks_updates=[float(x) for x in jax.tree.leaves(cks["updates"])],
+        loss=float(loss)).to_json() + "\n"
+
+
+class TestLedgerFetch:
+    """A ledger record's loss and checksums reach the host in one
+    transfer, with the values one ``float`` per scalar gives."""
+
+    STEPS, SLOT_EVERY = 4, 2
+    _runs = {}
+
+    @pytest.fixture
+    def counted_run(self, tmp_path_factory, monkeypatch):
+        """(trainer, tap, fetches, scalar pulls) of a short run in a mode;
+        the step function is compiled once for every mode."""
+        def run(mode):
+            if mode in self._runs:
+                return self._runs[mode]
+            tr = tiny_trainer(str(tmp_path_factory.mktemp(mode)), mode=mode,
+                              slot_every=self.SLOT_EVERY)
+            if self._runs:
+                tr.step_fn = next(iter(self._runs.values()))[1].fn
+            tap = tr.step_fn = _Tap(tr.step_fn)
+            calls = {"fetch": 0, "float": 0}
+            array_type = type(jnp.zeros(()))
+            device_get, array_float = jax.device_get, array_type.__float__
+
+            def counted_get(x):
+                calls["fetch"] += 1
+                return device_get(x)
+
+            def counted_float(x):
+                calls["float"] += 1
+                return array_float(x)
+            with monkeypatch.context() as m:
+                m.setattr(jax, "device_get", counted_get)
+                m.setattr(array_type, "__float__", counted_float)
+                tr.run(self.STEPS, log_every=0)
+            self._runs[mode] = (tr, tap, calls["fetch"], calls["float"])
+            return self._runs[mode]
+        return run
+
+    @pytest.mark.parametrize("mode", ["adcc", "sync"])
+    def test_ledger_bytes_match_per_leaf_pulls(self, counted_run, mode):
+        tr, tap, _, _ = counted_run(mode)
+        recorded = [t for t in range(self.STEPS)
+                    if mode == "adcc" or (t + 1) % self.SLOT_EVERY == 0]
+        want = "".join(_per_leaf_line(t, tr.tcfg.seed, *tap.outs[t])
+                       for t in recorded)
+        with open(tr.ledger.path) as fh:
+            assert fh.read() == want
+
+    @pytest.mark.parametrize("mode", ["adcc", "sync"])
+    def test_recovery_verifies_against_fetched_records(self, counted_run,
+                                                       mode):
+        tr, _, _, _ = counted_run(mode)
+        again = tiny_trainer(tr.workdir, mode=mode,
+                             slot_every=self.SLOT_EVERY)
+        params, _, start, report = again._try_recover()
+        # the newest slot whose step the validated ledger holds
+        valid = {r.step for r in again.ledger.validated_records()}
+        newest = max(step for _, step in again.store.slots_by_recency()
+                     if step in valid)
+        assert params is not None and start == newest + 1, report
+        assert report.endswith(f"@ step {newest} verified")
+        if mode == "adcc":
+            assert newest == self.STEPS - 1
+
+    @pytest.mark.parametrize("mode, fetches, pulls", [
+        ("adcc", STEPS, 0),
+        ("sync", STEPS // SLOT_EVERY, STEPS - STEPS // SLOT_EVERY),
+        ("none", 0, STEPS)])
+    def test_one_fetch_per_record(self, counted_run, mode, fetches, pulls):
+        _, _, got_fetches, got_pulls = counted_run(mode)
+        assert (got_fetches, got_pulls) == (fetches, pulls)
+
+    def test_wrapped_step_checksums_reach_the_ledger(self, tmp_path):
+        """A step function wrapped to scale its parameter checksums, as
+        the benchmark's altered-checksum fault does, is what the ledger
+        records."""
+        tr = tiny_trainer(str(tmp_path / "w"))
+        tap = _Tap(tr.step_fn)
+
+        def scaled(*args):
+            out = tap(*args)
+            cks = dict(out[4], params=jax.tree.map(lambda c: c * 2.0,
+                                                   out[4]["params"]))
+            return tuple(out[:4]) + (cks,)
+        tr.step_fn = scaled
+        tr.run(2, log_every=0)
+        recs = tr.ledger.read_all()
+        assert [r.step for r in recs] == [0, 1]
+        for rec, (loss, cks) in zip(recs, tap.outs):
+            assert rec.cks_params == [2.0 * float(x)
+                                      for x in jax.tree.leaves(cks["params"])]
+            assert rec.cks_opt == [float(x)
+                                   for x in jax.tree.leaves(cks["opt"])]
+            assert rec.loss == float(loss)
+
+    def test_fetch_record_values_splits_in_leaf_order(self):
+        cks = {"params": {"b": jnp.float32(2.5), "a": jnp.float32(-1e-8)},
+               "opt": (jnp.float32(3.0),),
+               "updates": {"b": jnp.float32(0.1), "a": jnp.float32(7.0)}}
+        got = fetch_record_values(jnp.float32(0.3), cks)
+        assert got == {"loss": float(jnp.float32(0.3)),
+                       "cks_params": [float(jnp.float32(-1e-8)), 2.5],
+                       "cks_opt": [3.0],
+                       "cks_updates": [7.0, float(jnp.float32(0.1))]}
 
 
 class TestElasticCheckpoint:
