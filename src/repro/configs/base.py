@@ -19,7 +19,8 @@ __all__ = ["ModelConfig", "ShapeConfig", "MeshConfig", "TrainConfig", "SHAPES"]
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                  # dense | moe | audio | vlm | hybrid | ssm
+    # dense | moe | audio | vlm | hybrid | ssm | nemotron_h
+    family: str
     n_layers: int
     d_model: int
     n_heads: int
@@ -34,6 +35,15 @@ class ModelConfig:
     n_shared_experts: int = 0
     moe_d_ff: int = 0            # per-expert FFN width
     capacity_factor: float = 1.25
+    # the held-experts layer (nemotron_h): factor on the routed experts'
+    # weights, and the shared expert's width
+    routed_scaling: float = 1.0
+    shared_d_ff: int = 0
+    # expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of the n_experts the router scores
+    # (0: all of them)
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # -- MLA (DeepSeek-style latent attention) ----------------------------
     use_mla: bool = False
@@ -49,9 +59,15 @@ class ModelConfig:
     ssm_conv_width: int = 4
     ssm_chunk: int = 128
     attn_every: int = 0          # hybrid: shared attn block every k ssm layers
+    ssm_heads: int = 0           # Mamba-2 heads (0: expand x d_model / head_dim)
+    ssm_groups: int = 1          # B/C groups, each read by heads / groups heads
+    ssm_gated_norm: bool = False  # group-wise RMSNorm of y * silu(z) before out_proj
+    # nemotron_h: one letter per block, M Mamba-2, E MoE, * attention
+    layer_pattern: str = ""
 
     # -- positional / misc ---------------------------------------------------
     rope_theta: float = 10_000.0
+    use_rope: bool = True        # False: attention applies no rotation
     mrope_sections: Tuple[int, int, int] = ()   # qwen2-vl M-RoPE
     causal: bool = True          # False => encoder-only (no decode shapes)
     embed_inputs: bool = True    # False => frontend stub supplies embeddings
@@ -75,6 +91,18 @@ class ModelConfig:
         dim shards evenly under any plausible TP degree (standard
         framework practice); logits are sliced back to ``vocab_size``."""
         return ((self.vocab_size + 255) // 256) * 256
+
+    @property
+    def ssm_inner(self) -> int:
+        """Mamba-2 inner width: heads x head_dim where the heads are
+        given, else expand x d_model."""
+        if self.ssm_heads:
+            return self.ssm_heads * self.ssm_head_dim
+        return self.ssm_expand * self.d_model
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def is_decoder(self) -> bool:
@@ -109,6 +137,12 @@ class ModelConfig:
             attn_every=2 if self.attn_every else 0,
             mrope_sections=(4, 6, 6) if self.mrope_sections else (),
             n_patches=16 if self.family == "vlm" else self.n_patches,
+            ssm_heads=8 if self.ssm_heads else 0,
+            ssm_groups=min(self.ssm_groups, 2),
+            shared_d_ff=64 if self.shared_d_ff else 0,
+            experts_held=min(self.experts_held, 4),
+            expert_offset=0,
+            layer_pattern="M*E" if self.layer_pattern else "",
         )
 
     # -- parameter counting (for MODEL_FLOPS = 6 N D) ---------------------------
@@ -141,6 +175,10 @@ class ModelConfig:
             nh = d_in // self.ssm_head_dim
             per_layer = (D * (2 * d_in + 2 * self.ssm_state + nh)
                          + d_in * D + self.ssm_conv_width * (d_in + 2 * self.ssm_state))
+        elif self.family == "nemotron_h":
+            return embed + D + sum(  # + the final norm
+                self._nemotron_block_params(c, active_only)
+                for c in self.layer_pattern)
         elif self.family == "hybrid":
             d_in = self.ssm_expand * D
             nh = d_in // self.ssm_head_dim
@@ -150,6 +188,23 @@ class ModelConfig:
                            + 3 * D * self.d_ff)  # one shared block
             return embed + L * mamba + shared_attn
         return embed + L * per_layer
+
+
+    def _nemotron_block_params(self, letter: str, active_only: bool) -> int:
+        D, hd = self.d_model, self.resolved_head_dim
+        if letter == "M":
+            E, G, N = self.ssm_inner, self.ssm_groups, self.ssm_state
+            H = E // self.ssm_head_dim
+            conv_ch = E + 2 * G * N
+            return (D * (E + conv_ch + H) + E * D
+                    + (self.ssm_conv_width + 1) * conv_ch + 3 * H + E + D)
+        if letter == "*":
+            return (D * self.n_heads * hd + 2 * D * self.n_kv_heads * hd
+                    + self.n_heads * hd * D + D)
+        experts = (self.experts_per_token if active_only
+                   else self.n_held_experts)
+        return (2 * D * self.moe_d_ff * experts + 2 * D * self.shared_d_ff
+                + D * self.n_experts + self.n_experts + D)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -195,3 +250,7 @@ class TrainConfig:
     fsdp: bool = True            # ZeRO-shard params/opt over the data axis
     grad_compression: str = "none"  # none | int8
     seed: int = 0
+    # the step donates its state (params, optimizer and error-feedback
+    # buffers) to its outputs, so old and new state are not held at once;
+    # a caller that reads a step's inputs after the step needs it off
+    donate_state: bool = False

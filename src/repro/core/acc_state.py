@@ -45,26 +45,40 @@ _CHECKSUM_GROUPS = ("params", "opt", "updates")
 
 
 @jax.jit
-def _record_vector(loss, cks):
-    """The loss, then every checksum in leaf order of each group, as one
-    f32 vector on the device."""
+def _record_vector(loss, cks, counts):
+    """The loss, then every checksum in leaf order of each group, then
+    every count flattened, as one f32 vector on the device."""
     leaves = [loss] + [x for g in _CHECKSUM_GROUPS
                        for x in jax.tree.leaves(cks[g])]
-    return jnp.stack([jnp.asarray(x, jnp.float32) for x in leaves])
+    vec = jnp.stack([jnp.asarray(x, jnp.float32) for x in leaves])
+    if not counts:
+        return vec
+    return jnp.concatenate([vec] + [jnp.ravel(c).astype(jnp.float32)
+                                    for c in jax.tree.leaves(counts)])
 
 
-def fetch_record_values(loss, cks) -> Dict[str, object]:
+def fetch_record_values(loss, cks, counts=None) -> Dict[str, object]:
     """A record's ``loss``, ``cks_params``, ``cks_opt`` and
     ``cks_updates`` from the step's f32 scalars, brought to the host in
     one transfer. Each value is the scalar widened to a Python float,
-    as ``float`` of it gives."""
-    values = jax.device_get(_record_vector(loss, cks)).tolist()
-    out: Dict[str, object] = {"loss": values[0]}
+    as ``float`` of it gives. ``counts``, a dict of small integer arrays
+    the step reports beside its loss (the routing counts), rides in the
+    same transfer and comes back under ``"counts"`` as int64 arrays of
+    their shapes; it is no part of the record."""
+    values = jax.device_get(_record_vector(loss, cks, counts or {}))
+    out: Dict[str, object] = {"loss": float(values[0])}
     at = 1
     for g in _CHECKSUM_GROUPS:
         n = len(jax.tree.leaves(cks[g]))
-        out["cks_" + g] = values[at:at + n]
+        out["cks_" + g] = values[at:at + n].tolist()
         at += n
+    if counts:
+        got = {}
+        for k, c in sorted(counts.items()):   # the leaf order of a dict
+            got[k] = np.rint(values[at:at + c.size]).astype(
+                np.int64).reshape(c.shape)
+            at += c.size
+        out["counts"] = got
     return out
 
 

@@ -7,6 +7,12 @@ benchmarks — so what we roofline is exactly what we'd run.
 train_step(params, opt_state, err_state, batch, rng) ->
     (new_params, new_opt_state, err_state, metrics, update_checksums)
 
+``metrics`` holds the loss, the gradient norm and, for a family whose
+``ModelApi.loss_and_counts`` reports them, the routing counts
+(``moe_rows``, ``moe_overflow``). ``donate=True`` donates params, optimizer
+and error-feedback state to the outputs (``TrainConfig.donate_state`` in
+the trainer).
+
 The ``update_checksums`` output is the ADCC hook (paper §III.C adapted —
 DESIGN.md §2): one f32 scalar per parameter tensor, the sum of the step's
 applied update. Because optimizer updates are applied *additively*, the
@@ -31,8 +37,8 @@ from ..configs.base import TrainConfig
 from ..models.registry import ModelApi
 from ..optim import compress_decompress, make_optimizer
 from ..optim.adamw import AdafactorState, AdamWState
-from ..sharding.partition import (PartitionRules, cache_shardings,
-                                  params_shardings)
+from ..sharding.partition import (ROUTER_F32, PartitionRules,
+                                  cache_shardings, params_shardings)
 
 __all__ = ["build_train_step", "build_serve_step", "tree_checksums",
            "build_opt_shardings"]
@@ -82,23 +88,35 @@ def build_train_step(api: ModelApi, tcfg: TrainConfig,
 
     compute_dtype = jnp.dtype(api.cfg.compute_dtype)
 
-    def to_compute(w):
+    params_shapes, axes = api.abstract_init(jax.random.PRNGKey(0))
+
+    def to_compute(w, ax):
         # bf16 compute copy of >=2D weights, cast *before* the layer scan
         # so FSDP all-gathers move bf16, not f32 masters (§Perf iter 3);
-        # 1D params (norms, A_log, dt_bias) stay f32 for numerics.
-        if w.dtype == jnp.float32 and w.ndim >= 2:
+        # 1D params (norms, A_log, dt_bias) stay f32 for numerics, and so
+        # does a weight its layer marks with the ROUTER_F32 axis
+        if w.dtype == jnp.float32 and w.ndim >= 2 and ROUTER_F32 not in ax:
             return w.astype(compute_dtype)
         return w
 
+    # a family with routing counts reports them beside the loss
+    with_counts = api.loss_and_counts is not None
+
     def train_step(params, opt_state, err_state, batch, rng):
         def loss_of(p):
-            return api.loss_fn(jax.tree.map(to_compute, p), batch, mesh,
-                               remat=tcfg.remat)
+            fn = api.loss_and_counts if with_counts else api.loss_fn
+            return fn(jax.tree.map(to_compute, p, axes), batch, mesh,
+                      remat=tcfg.remat)
 
         # stable names for the device's operations, whatever the HLO
         # numbering: model, optimizer, adcc.checksums
         with jax.named_scope("model"):
-            loss, grads = jax.value_and_grad(loss_of)(params)
+            if with_counts:
+                (loss, counts), grads = jax.value_and_grad(
+                    loss_of, has_aux=True)(params)
+            else:
+                loss, grads = jax.value_and_grad(loss_of)(params)
+                counts = {}
         with jax.named_scope("optimizer"):
             if use_compression:
                 grads, err_state = compress_decompress(grads, err_state, rng)
@@ -107,7 +125,8 @@ def build_train_step(api: ModelApi, tcfg: TrainConfig,
                 lambda p, u: p + u.astype(p.dtype), params, updates)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                              for g in jax.tree.leaves(grads)))
-        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm}
+        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gnorm,
+                   **counts}
         # ADCC scalars: direct sums of the new state fuse into the update's
         # HBM pass (the tensors are already streaming through registers);
         # the update sums additionally give the *linearity chain*
@@ -122,12 +141,11 @@ def build_train_step(api: ModelApi, tcfg: TrainConfig,
         return new_params, new_opt_state, err_state, metrics, checksums
 
     # --- shardings -----------------------------------------------------------
-    params_shapes, axes = api.abstract_init(jax.random.PRNGKey(0))
     params_sh = params_shardings(rules, axes)
     opt_sh = build_opt_shardings(tcfg, rules, params_sh, axes)
     err_sh = params_sh  # error-feedback buffers mirror params
     repl = NamedSharding(mesh, P())
-    metrics_sh = {"loss": repl, "grad_norm": repl}
+    metrics_sh = repl     # every metric, routing counts included
     checksums_sh = {
         "params": jax.tree.map(lambda _: repl, params_sh),
         "opt": jax.tree.map(lambda _: repl, opt_sh),

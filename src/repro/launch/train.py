@@ -18,6 +18,12 @@ crash/restart integration test.
 Also includes the step-time straggler monitor (flags slow hosts for the
 controller to replace — simulated single-host here, interface real).
 
+A family that routes tokens to experts reports its routing counts in the
+step's metrics; they join the ledger record's single fetch (no part of
+the record) and feed the ``moe`` counter group (:func:`count_routing`).
+With ``TrainConfig.donate_state`` the step donates its state, so a
+caller must not read a step's inputs after it.
+
 Each step is a ``train`` span root (repro.tracing) around the whole
 iteration, with the step function's call as its ``train.dispatch``
 child; the root's duration is the step time the monitor, the log line
@@ -59,6 +65,23 @@ __all__ = ["ADCCTrainer", "StragglerMonitor", "main"]
 # the restart ordinal of each recovery in this process, which its
 # adcc.recover span and their children carry
 _RESTARTS = itertools.count(1)
+
+# the step's metrics that count routing, fed to the ``moe`` counter group
+ROUTING_COUNTS = ("moe_rows", "moe_overflow")
+
+
+def count_routing(counts, expert_offset: int = 0) -> None:
+    """Add a step's routing counts to the ``moe`` counter group: the
+    rows routed to each held expert, keyed ``("rows", MoE layer,
+    expert)`` (the expert by its number among all the router's), and the
+    assignments the dropless buffer could not take, ``("overflow", MoE
+    layer)``."""
+    group = tracing.counter_group("moe")
+    for layer, per_expert in enumerate(np.asarray(counts["moe_rows"])):
+        for e, n in enumerate(per_expert):
+            group[("rows", layer, expert_offset + e)] += int(n)
+    for layer, n in enumerate(np.asarray(counts["moe_overflow"])):
+        group[("overflow", layer)] += int(n)
 
 
 class StragglerMonitor:
@@ -113,7 +136,7 @@ class ADCCTrainer:
         sample = {k: jnp.asarray(v)
                   for k, v in self.pipeline.batch_at(0).items()}
         self.step_fn, self.shardings, self.opt_init = build_train_step(
-            self.api, tcfg, self.rules, donate=False,
+            self.api, tcfg, self.rules, donate=tcfg.donate_state,
             batch_template=sample)
         self.ledger = ChecksumLedger(os.path.join(workdir, "ledger.jsonl"))
         self.store = SlotStore(os.path.join(workdir, "slots"), n_slots)
@@ -181,15 +204,23 @@ class ADCCTrainer:
                 # (3) synchronous tiny ledger write — the "one cache line";
                 # 'sync' writes one with each blocking checkpoint
                 boundary = (t + 1) % self.slot_every == 0
+                counts = {k: v for k, v in metrics.items()
+                          if k in ROUTING_COUNTS}
                 if self.mode == "adcc" or (self.mode == "sync" and boundary):
+                    values = fetch_record_values(metrics["loss"], cks, counts)
+                    counts = values.pop("counts", {})
                     rec = LedgerRecord(
                         step=t, rng_seed=self.tcfg.seed,
-                        cursor=[self.tcfg.seed, t + 1, 0],
-                        **fetch_record_values(metrics["loss"], cks))
+                        cursor=[self.tcfg.seed, t + 1, 0], **values)
                     self.ledger.append(rec)
                     loss = rec.loss
+                elif counts:
+                    loss, counts = jax.device_get((metrics["loss"], counts))
+                    loss = float(loss)
                 else:
                     loss = float(metrics["loss"])
+                if counts:
+                    count_routing(counts, self.cfg.expert_offset)
                 losses.append(loss)
 
                 if self.mode == "adcc" and boundary:

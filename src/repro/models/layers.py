@@ -1,4 +1,6 @@
-"""Shared transformer layers: norms, RoPE/M-RoPE, GQA attention, SwiGLU.
+"""Shared transformer layers: norms, RoPE/M-RoPE, GQA attention (XLA,
+Pallas flash for prefill, splash for a family that asks to train on it on
+one TPU chip), SwiGLU.
 
 Conventions
 -----------
@@ -190,8 +192,9 @@ def flash_sdpa(q: jax.Array, k: jax.Array, v: jax.Array, mesh, *,
     contiguous GQA ordering makes each shard's heads span whole KV
     groups whenever H/tp divides G or vice versa. Falls back to the
     caller's jnp path when the head count does not tile (checked by the
-    caller). Forward-only: the Pallas kernel has no VJP, so training
-    keeps the XLA attention."""
+    caller). Forward-only: the Pallas kernel has no VJP; training keeps
+    the XLA attention, or :func:`splash_causal` where the family asks
+    for it."""
     from jax.sharding import PartitionSpec as P
 
     from ..kernels.flash_attention.ops import flash_attention
@@ -232,6 +235,50 @@ def flash_applicable(cfg, q_heads: int, seq: int, mesh) -> bool:
     H_loc = q_heads // tp
     G = q_heads // max(cfg.n_kv_heads, 1)
     return (H_loc % G == 0) or (G % H_loc == 0)
+
+
+# --------------------------------------------------------------------------
+# splash attention — causal training path on one TPU chip (nemotron_h)
+# --------------------------------------------------------------------------
+
+SPLASH_BLOCK = 512
+
+
+def splash_applicable(seq: int, head_dim: int, mesh) -> bool:
+    """JAX's splash attention kernel (forward and backward, no S^2 HBM
+    tensor) runs where the default backend is a TPU, the layer is not
+    sharded over several devices, and the sequence and head tile by 128
+    lanes."""
+    return (jax.default_backend() == "tpu"
+            and (mesh is None or mesh.size == 1)
+            and seq % 128 == 0 and head_dim % 128 == 0)
+
+
+def splash_causal(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal softmax(q k^T / sqrt(hd)) v by splash attention, in its MQA
+    form once per KV head (each shared by H / KV query heads, contiguous
+    as ``jnp.repeat`` of the KV heads orders them). q: (B, S, H, hd);
+    k, v: (B, S, KV, hd) -> (B, S, H, hd)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+
+    B, S, H, hd = q.shape
+    KV = k.shape[2]
+    G = H // KV
+    blk = SPLASH_BLOCK if S % SPLASH_BLOCK == 0 else 128
+    sizes = sk.BlockSizes(block_q=blk, block_kv=blk, block_kv_compute=blk,
+                          block_q_dkv=blk, block_kv_dkv=blk,
+                          block_kv_dkv_compute=blk, block_q_dq=blk,
+                          block_kv_dq=blk)
+    mask = sm.MultiHeadMask([sm.CausalMask((S, S)) for _ in range(G)])
+    kernel = sk.make_splash_mqa_single_device(mask, block_sizes=sizes)
+    # the kernel takes the logits unscaled
+    qg = (q * (hd ** -0.5)).astype(q.dtype).reshape(B, S, KV, G, hd)
+    qg = jnp.transpose(qg, (0, 2, 3, 1, 4))            # (B, KV, G, S, hd)
+    kt = jnp.transpose(k, (0, 2, 1, 3))                 # (B, KV, S, hd)
+    vt = jnp.transpose(v, (0, 2, 1, 3))
+    out = jax.vmap(jax.vmap(kernel))(qg, kt, vt)        # (B, KV, G, S, hd)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(B, S, H, hd)
 
 
 # --------------------------------------------------------------------------
@@ -314,10 +361,12 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array,
                     mrope_positions: Optional[jax.Array] = None,
                     cache: Optional[Dict[str, jax.Array]] = None,
                     cache_index: Optional[jax.Array] = None,
-                    mesh=None, flash: bool = False):
+                    mesh=None, flash: bool = False, splash: bool = False):
     """Full attention. With ``cache`` (dict k/v (B, Smax, KV, hd)) performs
     one decode step: x is (B, 1, D), cache_index is the write position.
-    Returns (out, new_cache)."""
+    ``flash`` (prefill) and ``splash`` (training) ask for the causal
+    kernels where they apply; the caller's family chooses. Returns (out,
+    new_cache)."""
     B, S, D = x.shape
     hd = cfg.resolved_head_dim
     H, KV = cfg.n_heads, cfg.n_kv_heads
@@ -328,7 +377,8 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     if cfg.mrope_sections:
         q = apply_mrope(q, mrope_positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_mrope(k, mrope_positions, cfg.rope_theta, cfg.mrope_sections)
-    elif cfg.family != "audio":  # hubert frontend embeds positions already
+    elif cfg.family != "audio" and cfg.use_rope:
+        # (hubert's frontend embeds positions already)
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
@@ -344,6 +394,8 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     elif flash and cfg.causal and flash_applicable(cfg, H, S, mesh):
         # Pallas blockwise attention: prefill only (forward-only kernel)
         out = flash_sdpa(q, k, v, mesh, causal=True)
+    elif splash and cfg.causal and splash_applicable(S, hd, mesh):
+        out = splash_causal(q, k, v)
     else:
         out = _sdpa(q, k, v, causal=cfg.causal)
     out = out.reshape(B, S, H * hd)

@@ -1,25 +1,39 @@
 """Mixture-of-Experts FFN with expert parallelism.
 
-Two execution paths sharing one parameterization:
+Three execution paths:
 
 * ``moe_apply_dense`` — reference path: every expert computed on every
   token with mask-combine. O(T·E·F) compute, zero collectives. Used as
   the smoke-test/correctness oracle and for tiny reduced configs.
 
-* ``moe_apply_ep`` — production path under ``jax.shard_map``: tokens
+* ``moe_apply_ep`` — expert-parallel path under ``jax.shard_map``: tokens
   sharded over every mesh axis, experts sharded over the EP axis
   ("model"). Per shard: top-k routing -> capacity-bucketed all_to_all to
-  expert owners -> local ``jax.lax.ragged_dot`` grouped GEMM (sorted by
-  local expert) -> all_to_all back -> weighted combine at the source.
-  This is the TPU-native (GSPMD/ICI) analogue of the dispatch pipelines
-  GPU MoE stacks build with NCCL all-to-alls; the collective bytes it
-  emits are exactly what the roofline's collective term measures.
+  expert owners -> local grouped GEMM (sorted by local expert) ->
+  all_to_all back -> weighted combine at the source. This is the
+  TPU-native (GSPMD/ICI) analogue of the dispatch pipelines GPU MoE
+  stacks build with NCCL all-to-alls; the collective bytes it emits are
+  exactly what the roofline's collective term measures.
 
-Capacity: each destination device receives at most
-``ceil(T_loc * K * capacity_factor / ep)`` tokens; overflow assignments
-are dropped (weights renormalized upstream make this a standard
-capacity-drop MoE). Tests run with generous capacity and assert the EP
-path matches the dense oracle exactly.
+  Capacity: each destination device receives at most
+  ``ceil(T_loc * K * capacity_factor / ep)`` tokens; overflow
+  assignments are dropped (weights renormalized upstream make this a
+  standard capacity-drop MoE). Tests run with generous capacity and
+  assert the EP path matches the dense oracle exactly.
+
+* ``moe_held_apply`` — one chip's share of an expert-parallel layer
+  (the ``nemotron_h`` family): the layer is told which experts it holds
+  (``cfg.expert_offset``, ``cfg.experts_held`` of ``cfg.n_experts``),
+  routes every token over all of them and computes only its held
+  experts' part of the result, plus the shared expert. Dropless: the
+  assignments to held experts are gathered in expert order into a buffer
+  of static size ``T * min(K, held)``, which no routing can overflow, and
+  run as grouped products (``jax.lax.ragged_dot``) over the routed
+  counts. Its router is DeepSeek-V3's: float32 logits, sigmoid scores,
+  the top-k of the scores plus a correction bias (used for the choice
+  alone), the chosen scores renormalized to sum 1 and scaled by
+  ``cfg.routed_scaling``. Its experts, routed and shared, are relu^2
+  MLPs with no gate: ``W_down relu(W_up x)^2``.
 """
 
 from __future__ import annotations
@@ -31,9 +45,14 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
+from ..sharding.partition import ROUTER_F32
 from .layers import Axes, Params, dense_init
 
-__all__ = ["moe_init", "moe_apply_dense", "moe_apply_ep", "router_topk"]
+__all__ = ["moe_init", "moe_apply_dense", "moe_apply_ep", "router_topk",
+           "moe_held_init", "moe_held_apply", "route", "routed_experts",
+           "shared_expert"]
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def moe_init(cfg: ModelConfig, key) -> Tuple[Params, Axes]:
@@ -233,3 +252,104 @@ def moe_apply_ep(cfg: ModelConfig, p: Params, x_tokens: jax.Array,
         check_vma=False,
     )(x_tokens, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     return out[:n_tokens] if pad else out
+
+
+# ---------------------------------------------------------------------------
+# one chip's share of an expert-parallel layer (nemotron_h)
+# ---------------------------------------------------------------------------
+
+def moe_held_init(cfg: ModelConfig, key) -> Tuple[Params, Axes]:
+    """Router over all ``n_experts`` (float32, with its correction bias),
+    the held experts' relu^2 MLPs and the shared expert. The router's
+    output axis is ``ROUTER_F32``, which keeps it float32 in the train
+    step's compute copy."""
+    D, F = cfg.d_model, cfg.moe_d_ff
+    E_h, Fs = cfg.n_held_experts, cfg.shared_d_ff
+    dtype = jnp.dtype(cfg.param_dtype)
+    ks = jax.random.split(key, 5)
+    p, a = {}, {}
+    p["router"], a["router"] = dense_init(ks[0], D, cfg.n_experts, "embed",
+                                          ROUTER_F32, jnp.float32)
+    p["bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    a["bias"] = (ROUTER_F32,)
+
+    def expert_stack(k, din, dout):
+        w = jax.random.normal(k, (E_h, din, dout), jnp.float32)
+        return (w * (2.0 / (din + dout)) ** 0.5).astype(dtype)
+
+    p["w_up"] = expert_stack(ks[1], D, F)
+    a["w_up"] = ("experts", "embed", "mlp_e")
+    p["w_down"] = expert_stack(ks[2], F, D)
+    a["w_down"] = ("experts", "mlp_e", "embed")
+    shared, sa = {}, {}
+    shared["w_up"], sa["w_up"] = dense_init(ks[3], D, Fs, "embed", "mlp",
+                                            dtype)
+    shared["w_down"], sa["w_down"] = dense_init(ks[4], Fs, D, "mlp",
+                                                "embed", dtype)
+    p["shared"], a["shared"] = shared, sa
+    return p, a
+
+
+def route(cfg: ModelConfig, router_w: jax.Array, bias: jax.Array,
+          x: jax.Array):
+    """(weights (T,K) f32, ids (T,K) int32) over all ``n_experts`` for
+    tokens x (T,D): sigmoid scores of the float32 logits, the top-k of
+    scores + bias, the chosen scores renormalized and scaled."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias, cfg.experts_per_token)
+    weights = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return weights * cfg.routed_scaling, ids.astype(jnp.int32)
+
+
+def _relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
+def routed_experts(cfg: ModelConfig, p: Params, x: jax.Array):
+    """The held experts' part of the layer for tokens x (T,D), and the
+    routing's counts: ``rows`` (held,) routed to each held expert and
+    ``overflow``, the held assignments the buffer could not take (0 by
+    construction)."""
+    T, D = x.shape
+    K, E_h = cfg.experts_per_token, cfg.n_held_experts
+    dt = x.dtype
+    with jax.named_scope("moe.router"):
+        weights, ids = route(cfg, p["router"], p["bias"], x)
+        local = ids.reshape(-1) - cfg.expert_offset
+        # assignments to absent experts sort after every held one
+        key = jnp.where((local >= 0) & (local < E_h), local, E_h)
+        rows = jnp.bincount(key, length=E_h + 1).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)[:T * min(K, E_h)]
+        held = key[order] < E_h
+        token = order // K
+        w = jnp.where(held, weights.reshape(-1)[order], 0.0)
+        overflow = jnp.sum(rows[:E_h]) - jnp.sum(held, dtype=jnp.int32)
+    with jax.named_scope("moe.experts"):
+        # the TPU's grouped product leaves the rows past the groups
+        # undefined: every product's output is selected to its held rows
+        # (a zero weight would keep a NaN), so what each product reads,
+        # forward and backward, is finite
+        def grouped(a, w_e):
+            return jnp.where(held[:, None], jax.lax.ragged_dot(
+                a, w_e.astype(dt), rows[:E_h]), 0)
+
+        xs = jnp.where(held[:, None], x[token], 0).astype(dt)
+        y = grouped(_relu2(grouped(xs, p["w_up"])), p["w_down"])
+        out = jnp.zeros((T, D), jnp.float32).at[token].add(
+            y.astype(jnp.float32) * w[:, None])
+    return out.astype(dt), {"rows": rows[:E_h], "overflow": overflow}
+
+
+def shared_expert(p: Params, x: jax.Array) -> jax.Array:
+    dt = x.dtype
+    with jax.named_scope("moe.shared"):
+        return _relu2(x @ p["w_up"].astype(dt)) @ p["w_down"].astype(dt)
+
+
+def moe_held_apply(cfg: ModelConfig, p: Params, x: jax.Array):
+    """x (T,D) -> (held experts' part + shared expert (T,D), counts)."""
+    y, counts = routed_experts(cfg, p, x)
+    return y + shared_expert(p["shared"], x), counts

@@ -22,6 +22,7 @@ ARCHS = {
     "qwen2-vl-2b": "repro.configs.qwen2_vl_2b",
     "zamba2-1.2b": "repro.configs.zamba2_1_2b",
     "mamba2-130m": "repro.configs.mamba2_130m",
+    "nemotron3-nano-30b-a3b": "repro.configs.nemotron3_nano_30b_a3b",
 }
 
 
@@ -36,6 +37,10 @@ class ModelApi:
     loss_fn: Callable              # (params, batch, mesh=None, remat=...) -> loss
     init_cache: Optional[Callable]  # (batch, max_len) -> (cache, axes)
     decode_step: Optional[Callable]  # (params, cache, tokens, pos, mesh) -> ...
+    # (params, batch, mesh=None, remat=...) -> (loss, counts): the loss
+    # with the counts the step reports beside it (a family that has
+    # some); the train step then differentiates this in place of loss_fn
+    loss_and_counts: Optional[Callable] = None
 
 
 def _lm_api(cfg: ModelConfig) -> ModelApi:
@@ -88,6 +93,25 @@ def _hybrid_api(cfg: ModelConfig) -> ModelApi:
     )
 
 
+def _nemotron_h_api(cfg: ModelConfig) -> ModelApi:
+    from . import nemotron_h
+    return ModelApi(
+        cfg=cfg,
+        init=lambda key: nemotron_h.init(cfg, key),
+        abstract_init=lambda key: nemotron_h.abstract_init(cfg, key),
+        forward=lambda p, b, mesh=None, remat="none": nemotron_h.forward(
+            cfg, p, b, mesh, remat=remat)[0],
+        loss_fn=lambda p, b, mesh=None, remat="none": nemotron_h.loss_fn(
+            cfg, p, b, mesh, remat=remat)[0],
+        init_cache=lambda batch, max_len: nemotron_h.init_cache(
+            cfg, batch, max_len),
+        decode_step=lambda p, c, t, pos, mesh=None: nemotron_h.decode_step(
+            cfg, p, c, t, pos, mesh),
+        loss_and_counts=lambda p, b, mesh=None, remat="none":
+        nemotron_h.loss_fn(cfg, p, b, mesh, remat=remat),
+    )
+
+
 def get_config(arch: str) -> ModelConfig:
     if arch not in ARCHS:
         raise KeyError(f"unknown arch {arch!r}; choose from {sorted(ARCHS)}")
@@ -101,6 +125,8 @@ def build_model(cfg_or_arch) -> ModelApi:
         return _ssm_api(cfg)
     if cfg.family == "hybrid":
         return _hybrid_api(cfg)
+    if cfg.family == "nemotron_h":
+        return _nemotron_h_api(cfg)
     return _lm_api(cfg)
 
 

@@ -25,7 +25,13 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["PartitionRules", "make_rules", "spec_for_axes", "params_shardings",
-           "batch_shardings", "cache_shardings", "logical_to_spec"]
+           "batch_shardings", "cache_shardings", "logical_to_spec",
+           "ROUTER_F32"]
+
+# output axis of a router whose logits are computed in float32 (the
+# held-experts layer's): replicated, and a weight that has it stays
+# float32 in the train step's compute copy
+ROUTER_F32 = "experts_f32"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,6 +89,7 @@ def make_rules(mesh: Mesh, *, fsdp: bool = True, sp: bool = False,
         "vocab": "model",
         "experts": "model",                  # EP
         "experts_r": None,                   # router output dim (small)
+        ROUTER_F32: None,                    # the same, kept f32 in compute
         "kv_lora": None,
         "layers": None,
         "ssm_inner": "model",                # mamba out_proj contraction dim

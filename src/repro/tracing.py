@@ -11,7 +11,13 @@ its parent's attributes under its own: the training ``step`` of the
 ``train`` root, the ``restart`` ordinal of ``adcc.recover``.
 ``step(name, step_num)`` opens a root through
 ``jax.profiler.StepTraceAnnotation``. ``counter_group(name)`` is the
-registry's ``collections.Counter`` of that name.
+registry's ``collections.Counter`` of that name. The groups in use:
+
+* ``moe`` — the held-experts layer's routing, added once a step from
+  the values the ledger record's fetch brings
+  (``launch/train.py::count_routing``): ``("rows", layer, expert)``
+  rows routed to each held expert, ``("overflow", layer)`` held
+  assignments the dropless buffer could not take (0 by construction).
 
 Always on: with no profiler running a span costs a few
 microseconds, against a training step of hundreds of milliseconds. Spans

@@ -79,7 +79,8 @@ class TestArchSmoke:
 
 
 def test_all_ten_archs_registered():
-    assert len(ARCHS) == 10
+    # the ten assigned architectures and nemotron3-nano-30b-a3b
+    assert len(ARCHS) == 11
 
 
 @pytest.mark.parametrize("arch,expected_b", [
@@ -87,6 +88,7 @@ def test_all_ten_archs_registered():
     ("deepseek-v2-lite-16b", 16.0), ("kimi-k2-1t-a32b", 1000.0),
     ("hubert-xlarge", 1.0), ("qwen2-vl-2b", 1.5), ("zamba2-1.2b", 1.2),
     ("mamba2-130m", 0.13), ("granite-3-8b", 8.0),
+    ("nemotron3-nano-30b-a3b", 31.6),
 ])
 def test_param_counts_match_published(arch, expected_b):
     n = get_config(arch).param_count() / 1e9

@@ -3,7 +3,8 @@
 The TPU compiler refuses what interpret mode accepts: block shapes that
 break the (8, 128) tiling rule, 64-bit types inside a Mosaic kernel,
 programs that overflow a chip's memory. These tests compile, at real
-widths, the Pallas kernels and the batched sweep's device launches at
+widths, the Pallas kernels, nemotron3-nano-30b-a3b's splash attention and
+grouped expert products, and the batched sweep's device launches at
 the sizes of the figures that drive them (fig_torn CG n=2048 and MM
 n=64, fig_kv's KV rows). Nothing runs.
 
@@ -79,6 +80,40 @@ def test_flash_attention_pallas_gqa4(one_chip):
     compiled = _compile(
         lambda q, k, v: flash_attention_pallas(q, k, v, groups=4), q, kv, kv)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_splash_causal_training_gqa16_8k(one_chip):
+    """nemotron3-nano-30b-a3b's attention, forward and backward: 32 query
+    heads over 2 KV heads, head dim 128, 8,192 positions."""
+    from repro.models.layers import splash_causal
+    q = _spec(one_chip, (1, 8192, 32, 128), jnp.bfloat16)
+    kv = _spec(one_chip, (1, 8192, 2, 128), jnp.bfloat16)
+    loss = lambda q, k, v: jnp.sum(splash_causal(q, k, v).astype(jnp.float32))
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mqa_{phase}" in text, phase
+
+
+def test_held_experts_grouped_products(one_chip):
+    """nemotron3-nano-30b-a3b's held experts, forward and backward: 16,384
+    tokens routed over 128 experts, 6 each, 8 held, widths 2688 and 1856;
+    the grouped products lower to the TPU's ragged-dot kernel."""
+    import dataclasses
+
+    from repro.models import moe
+    from repro.models.registry import get_config
+    cfg = dataclasses.replace(get_config("nemotron3-nano-30b-a3b"),
+                              experts_held=8)
+    D, F = cfg.d_model, cfg.moe_d_ff
+    p = {"router": _spec(one_chip, (D, 128), jnp.float32),
+         "bias": _spec(one_chip, (128,), jnp.float32),
+         "w_up": _spec(one_chip, (8, D, F), jnp.bfloat16),
+         "w_down": _spec(one_chip, (8, F, D), jnp.bfloat16)}
+    x = _spec(one_chip, (16384, D), jnp.bfloat16)
+    loss = lambda p, x: jnp.sum(
+        moe.routed_experts(cfg, p, x)[0].astype(jnp.float32))
+    text = _compile(jax.grad(loss, argnums=(0, 1)), p, x).as_text()
+    assert "tpu_custom_call" in text and "ragged" in text
 
 
 def _fig_torn_cg_operator():
