@@ -53,6 +53,7 @@ from ..core.acc_state import (ChecksumLedger, LedgerRecord,
 from ..core.slots import (AsyncSlotWriter, SlotStore, flatten_state,
                           unflatten_state)
 from ..data.pipeline import SyntheticPipeline
+from ..models.moe import buffer_ladder, buffer_rung
 from ..models.registry import build_model, get_config
 from ..optim import init_error_state
 from ..sharding.partition import make_rules
@@ -70,16 +71,23 @@ _RESTARTS = itertools.count(1)
 ROUTING_COUNTS = ("moe_rows", "moe_overflow")
 
 
-def count_routing(counts, expert_offset: int = 0) -> None:
+def count_routing(counts, cfg: ModelConfig, tokens: int) -> None:
     """Add a step's routing counts to the ``moe`` counter group: the
     rows routed to each held expert, keyed ``("rows", MoE layer,
-    expert)`` (the expert by its number among all the router's), and the
+    expert)`` (the expert by its number among all the router's), the
     assignments the dropless buffer could not take, ``("overflow", MoE
-    layer)``."""
+    layer)``, and the rows of the buffer the layer ran over,
+    ``("buffer_rows", MoE layer)``: the smallest of
+    :func:`~repro.models.moe.buffer_ladder` (``tokens`` a layer) that
+    takes the layer's held rows."""
     group = tracing.counter_group("moe")
+    ladder = buffer_ladder(tokens, cfg.experts_per_token,
+                           cfg.n_held_experts, cfg.n_experts)
     for layer, per_expert in enumerate(np.asarray(counts["moe_rows"])):
         for e, n in enumerate(per_expert):
-            group[("rows", layer, expert_offset + e)] += int(n)
+            group[("rows", layer, cfg.expert_offset + e)] += int(n)
+        group[("buffer_rows", layer)] += buffer_rung(ladder,
+                                                     int(per_expert.sum()))
     for layer, n in enumerate(np.asarray(counts["moe_overflow"])):
         group[("overflow", layer)] += int(n)
 
@@ -220,7 +228,7 @@ class ADCCTrainer:
                 else:
                     loss = float(metrics["loss"])
                 if counts:
-                    count_routing(counts, self.cfg.expert_offset)
+                    count_routing(counts, self.cfg, self.batch * self.seq)
                 losses.append(loss)
 
                 if self.mode == "adcc" and boundary:

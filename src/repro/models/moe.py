@@ -27,17 +27,20 @@ Three execution paths:
   routes every token over all of them and computes only its held
   experts' part of the result, plus the shared expert. Dropless: the
   assignments to held experts are gathered in expert order into a buffer
-  of static size ``T * min(K, held)``, which no routing can overflow, and
-  run as grouped products (``jax.lax.ragged_dot``) over the routed
-  counts. Its router is DeepSeek-V3's: float32 logits, sigmoid scores,
-  the top-k of the scores plus a correction bias (used for the choice
-  alone), the chosen scores renormalized to sum 1 and scaled by
+  and run as grouped products (``jax.lax.ragged_dot``) over the routed
+  counts. The buffer is the smallest of a short ladder of static sizes
+  (``buffer_ladder``) that takes the step's held rows, chosen on the
+  device; the largest, ``T * min(K, held)``, takes any routing. Its
+  router is DeepSeek-V3's: float32 logits, sigmoid scores, the top-k of
+  the scores plus a correction bias (used for the choice alone), the
+  chosen scores renormalized to sum 1 and scaled by
   ``cfg.routed_scaling``. Its experts, routed and shared, are relu^2
   MLPs with no gate: ``W_down relu(W_up x)^2``.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -50,7 +53,7 @@ from .layers import Axes, Params, dense_init
 
 __all__ = ["moe_init", "moe_apply_dense", "moe_apply_ep", "router_topk",
            "moe_held_init", "moe_held_apply", "route", "routed_experts",
-           "shared_expert"]
+           "shared_expert", "buffer_ladder", "buffer_rung", "rung_index"]
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -308,39 +311,123 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+# the held experts' buffer sizes are whole multiples of this many rows
+BUFFER_ALIGN = 128
+
+
+def buffer_ladder(tokens: int, top_k: int, held: int,
+                  n_experts: int) -> Tuple[int, ...]:
+    """The held experts' buffer sizes (rows), largest first, from shapes
+    alone, so that the device and the host compute the same ladder. The
+    largest is ``tokens * min(top_k, held)``, which every routing fits
+    (dropless); each next is half the one above rounded up to a multiple
+    of ``BUFFER_ALIGN``, down to the first at or below twice the balanced
+    held load, ``2 tokens top_k held / n_experts``. Where halving would not
+    shrink the buffer (decode sizes) the ladder is the one buffer."""
+    rungs = [tokens * min(top_k, held)]
+    while rungs[-1] > 2 * tokens * top_k * held / n_experts:
+        half = -(-rungs[-1] // 2)
+        half = -(-half // BUFFER_ALIGN) * BUFFER_ALIGN
+        if half >= rungs[-1]:
+            break
+        rungs.append(half)
+    return tuple(rungs)
+
+
+def buffer_rung(ladder: Tuple[int, ...], held_rows: int) -> int:
+    """The smallest buffer of ``ladder`` that takes ``held_rows`` rows."""
+    return min(r for r in ladder if r >= held_rows)
+
+
+def rung_index(ladder: Tuple[int, ...], held_rows: jax.Array) -> jax.Array:
+    """:func:`buffer_rung`'s index in ``ladder``, on the device."""
+    return jnp.sum(jnp.asarray(ladder) >= held_rows) - 1
+
+
+def _held_part(size: int, K: int, x, weights, order, rows, w_up, w_down):
+    """The held experts' part (T,D) float32 over a buffer of ``size`` rows:
+    the first ``size`` assignments of ``order`` (every held one, as absent
+    experts sort last), gathered, run through the grouped products and
+    combined with their ``weights`` (T*K,)."""
+    dt = x.dtype
+    order = order[:size]
+    held = jnp.arange(size) < jnp.sum(rows)
+    token = order // K
+    w = jnp.where(held, weights[order], 0.0)
+
+    # the TPU's grouped product leaves the rows past the groups
+    # undefined: every product's output is selected to its held rows
+    # (a zero weight would keep a NaN), so what each product reads,
+    # forward and backward, is finite
+    def grouped(a, w_e):
+        return jnp.where(held[:, None], jax.lax.ragged_dot(
+            a, w_e.astype(dt), rows), 0)
+
+    xs = jnp.where(held[:, None], x[token], 0).astype(dt)
+    y = grouped(_relu2(grouped(xs, w_up)), w_down)
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(
+        y.astype(jnp.float32) * w[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _held_experts(ladder, K, rung, x, weights, order, rows, w_up, w_down):
+    """:func:`_held_part` at the buffer ``ladder[rung]``. Its derivative
+    saves the layer's inputs alone and recomputes that one buffer's
+    forward in the backward pass (a plain switch would save every
+    buffer's residuals)."""
+    return jax.lax.switch(rung, [functools.partial(_held_part, s, K)
+                                 for s in ladder],
+                          x, weights, order, rows, w_up, w_down)
+
+
+def _held_experts_fwd(ladder, K, rung, x, weights, order, rows, w_up,
+                      w_down):
+    out = _held_experts(ladder, K, rung, x, weights, order, rows, w_up,
+                        w_down)
+    return out, (rung, x, weights, order, rows, w_up, w_down)
+
+
+def _held_experts_bwd(ladder, K, res, g):
+    rung, x, weights, order, rows, w_up, w_down = res
+
+    def branch(size):
+        def vjp(x, weights, w_up, w_down):
+            part = lambda x, weights, w_up, w_down: _held_part(
+                size, K, x, weights, order, rows, w_up, w_down)
+            return jax.vjp(part, x, weights, w_up, w_down)[1](g)
+        return vjp
+
+    dx, dweights, dw_up, dw_down = jax.lax.switch(
+        rung, [branch(s) for s in ladder], x, weights, w_up, w_down)
+    return None, dx, dweights, None, None, dw_up, dw_down
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
+
+
 def routed_experts(cfg: ModelConfig, p: Params, x: jax.Array):
     """The held experts' part of the layer for tokens x (T,D), and the
     routing's counts: ``rows`` (held,) routed to each held expert and
     ``overflow``, the held assignments the buffer could not take (0 by
-    construction)."""
+    construction). The part runs over the smallest buffer of
+    :func:`buffer_ladder` that takes the step's held rows."""
     T, D = x.shape
     K, E_h = cfg.experts_per_token, cfg.n_held_experts
-    dt = x.dtype
+    ladder = buffer_ladder(T, K, E_h, cfg.n_experts)
     with jax.named_scope("moe.router"):
         weights, ids = route(cfg, p["router"], p["bias"], x)
         local = ids.reshape(-1) - cfg.expert_offset
         # assignments to absent experts sort after every held one
         key = jnp.where((local >= 0) & (local < E_h), local, E_h)
-        rows = jnp.bincount(key, length=E_h + 1).astype(jnp.int32)
-        order = jnp.argsort(key, stable=True)[:T * min(K, E_h)]
-        held = key[order] < E_h
-        token = order // K
-        w = jnp.where(held, weights.reshape(-1)[order], 0.0)
-        overflow = jnp.sum(rows[:E_h]) - jnp.sum(held, dtype=jnp.int32)
+        rows = jnp.bincount(key, length=E_h + 1).astype(jnp.int32)[:E_h]
+        order = jnp.argsort(key, stable=True)[:ladder[0]]
+        n_held = jnp.sum(rows)
+        overflow = n_held - jnp.sum(key[order] < E_h, dtype=jnp.int32)
+        rung = rung_index(ladder, n_held)
     with jax.named_scope("moe.experts"):
-        # the TPU's grouped product leaves the rows past the groups
-        # undefined: every product's output is selected to its held rows
-        # (a zero weight would keep a NaN), so what each product reads,
-        # forward and backward, is finite
-        def grouped(a, w_e):
-            return jnp.where(held[:, None], jax.lax.ragged_dot(
-                a, w_e.astype(dt), rows[:E_h]), 0)
-
-        xs = jnp.where(held[:, None], x[token], 0).astype(dt)
-        y = grouped(_relu2(grouped(xs, p["w_up"])), p["w_down"])
-        out = jnp.zeros((T, D), jnp.float32).at[token].add(
-            y.astype(jnp.float32) * w[:, None])
-    return out.astype(dt), {"rows": rows[:E_h], "overflow": overflow}
+        out = _held_experts(ladder, K, rung, x, weights.reshape(-1), order,
+                            rows, p["w_up"], p["w_down"])
+    return out.astype(x.dtype), {"rows": rows, "overflow": overflow}
 
 
 def shared_expert(p: Params, x: jax.Array) -> jax.Array:

@@ -17,7 +17,8 @@ registry's ``collections.Counter`` of that name. The groups in use:
   the values the ledger record's fetch brings
   (``launch/train.py::count_routing``): ``("rows", layer, expert)``
   rows routed to each held expert, ``("overflow", layer)`` held
-  assignments the dropless buffer could not take (0 by construction).
+  assignments the dropless buffer could not take (0 by construction),
+  ``("buffer_rows", layer)`` the rows of the buffer the layer ran over.
 
 Always on: with no profiler running a span costs a few
 microseconds, against a training step of hundreds of milliseconds. Spans

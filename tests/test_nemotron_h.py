@@ -1,7 +1,8 @@
 """The nemotron_h family through the trainer: a donated step against an
 undonated one (bit-identical losses and ledger records, and a crash
-recovered from its slots), and the mamba2-130m step's program unchanged
-by what the family added to the shared code."""
+recovered from its slots), the mamba2-130m step's program unchanged
+by what the family added to the shared code, and the held experts'
+ladder of buffer sizes."""
 
 import dataclasses
 import hashlib
@@ -17,6 +18,7 @@ from repro.core.acc_state import ChecksumLedger
 from repro.launch.mesh import single_device_mesh
 from repro.launch.steps import build_train_step
 from repro.launch.train import ADCCTrainer
+from repro.models import moe
 from repro.models.registry import build_model, get_config
 from repro.optim import init_error_state
 from repro.sharding.partition import make_rules
@@ -143,3 +145,68 @@ def test_step_reports_routing_counts(donate):
     assert int(metrics["moe_overflow"][0]) == 0
     # every token picks 2 of 8 experts; the held 4 take some of them
     assert 0 < int(jnp.sum(metrics["moe_rows"])) <= 2 * 32 * 2
+
+
+# ---------------------------------------------------------------------------
+# the held experts' ladder of buffer sizes
+# ---------------------------------------------------------------------------
+
+# (experts per token, held experts, the router's experts): the benchmark
+# cell's layer, the reduced configurations', and shares that hold one,
+# a few or every expert
+SHARES = [(6, 8, 128), (2, 4, 8), (3, 4, 16), (1, 1, 16), (6, 16, 16),
+          (8, 2, 256), (2, 64, 64)]
+
+
+@pytest.mark.parametrize("tokens", [1, 2, 64, 1000, 8192, 16384])
+def test_ladder_top_rung_is_the_dropless_buffer(tokens):
+    """The largest buffer takes every routing; each next is half the one
+    above rounded up to 128 rows, strictly smaller, and the ladder stops
+    at the first at or below twice the balanced held load."""
+    for K, held, E in SHARES:
+        ladder = moe.buffer_ladder(tokens, K, held, E)
+        assert ladder[0] == tokens * min(K, held)
+        floor = 2 * tokens * K * held / E
+        for above, r in zip(ladder, ladder[1:]):
+            assert r % 128 == 0 and r < above
+            assert r == -(-(-(-above // 2)) // 128) * 128
+            assert above > floor
+        # it stops at the floor, or where halving no longer shrinks it
+        last = ladder[-1]
+        assert last <= floor or -(-(-(-last // 2)) // 128) * 128 >= last
+
+
+def test_the_cells_ladder():
+    """nemotron3-nano-30b-a3b's MoE layer: 16,384 tokens, top-6 of 128,
+    8 held; the floor is twice its balanced load of 6,144 rows."""
+    assert moe.buffer_ladder(16384, 6, 8, 128) == (98304, 49152, 24576,
+                                                   12288)
+
+
+@pytest.mark.parametrize("tokens", [64, 1000, 16384])
+def test_the_rung_takes_the_held_rows_and_less_than_twice(tokens):
+    """The rung chosen is the smallest that takes the held rows; above
+    the ladder's floor it is under twice them; the device's choice is the
+    host's."""
+    index = jax.jit(moe.rung_index, static_argnums=0)
+    for K, held, E in SHARES:
+        ladder = moe.buffer_ladder(tokens, K, held, E)
+        held_rows = {0, 1, ladder[-1], ladder[-1] + 1, ladder[0],
+                     *range(0, ladder[0], max(1, ladder[0] // 37))}
+        for n in sorted(n for n in held_rows if n <= ladder[0]):
+            r = moe.buffer_rung(ladder, n)
+            assert r >= n and r == min(x for x in ladder if x >= n)
+            if r != ladder[-1]:
+                assert r < 2 * n
+            assert ladder[int(index(ladder, n))] == r
+
+
+@pytest.mark.parametrize("batch", [1, 2, 8, 16])
+def test_decode_sizes_are_one_rung(batch):
+    """One token per row: the cell's layer at decode batch sizes, and any
+    buffer of at most 128 rows, is the one buffer it had."""
+    assert moe.buffer_ladder(batch, 6, 8, 128) == (batch * 6,)
+    for K, held, E in SHARES:
+        ladder = moe.buffer_ladder(batch, K, held, E)
+        if ladder[0] <= 128:
+            assert ladder == (batch * min(K, held),)
